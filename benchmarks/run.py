@@ -59,6 +59,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.api.result import jsonify
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = {
         "quality": _suite("quality", lambda: dict(
@@ -116,12 +119,13 @@ def main() -> None:
         print(f"# === {name} ===", flush=True)
         try:
             rows = fn()
-        except Exception as e:  # keep the suite running
+        except Exception as e:  # run the other suites, then exit non-zero
             print(f"{name},0.0,ERROR={type(e).__name__}:{e}", flush=True)
             report["suites"][name] = {"error": f"{type(e).__name__}: {e}"}
         else:
             report["suites"][name] = {"rows": jsonify(rows)}
     report["seconds"] = time.time() - t0
+    errored = [n for n, r in report["suites"].items() if "error" in r]
     if args.json:
         with open(args.json, "w") as f:
             json.dump(report, f, indent=2, sort_keys=True)
@@ -155,6 +159,9 @@ def main() -> None:
             f"# bench gate OK vs {args.baseline} ({compared} metrics compared)",
             file=sys.stderr,
         )
+    if errored:
+        print(f"# FAILED: suite(s) errored: {','.join(errored)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

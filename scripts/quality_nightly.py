@@ -77,6 +77,9 @@ def fetched_file_graph(workdir: Path, n: int, seed: int):
         fh.write("# synthetic SNAP-style edge list (nightly)\n")
         np.savetxt(fh, g.edges_array(), fmt="%d")
     bin_path = workdir / "nightly.bin"
+    # the child only converts and initialises no JAX backend, so it never
+    # competes for the accelerator this process holds after partitioning
+    # (pinned by tests/test_outofcore.py)
     subprocess.run(
         [
             sys.executable, str(REPO / "scripts" / "fetch_dataset.py"),

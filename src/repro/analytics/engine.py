@@ -69,14 +69,18 @@ class GraphEngine:
         self.ctx = dict(ctx or {})
         self.ctx.setdefault("num_vertices", lg.num_vertices)
 
+    def graph_arrays(self) -> tuple[np.ndarray, ...]:
+        """``(rows, cols, degrees_full, send_gather)``: the local graph as
+        the step functions take it. It is passed as arguments, not folded
+        into the program as constants, so the program's size does not grow
+        with the graph."""
+        lg = self.lg
+        return lg.rows, lg.cols, lg.degrees_full, lg.send_gather
+
     # ------------------------------------------------------------ simulated
     @functools.cached_property
     def _sim_step(self):
         lg, program, ctx = self.lg, self.program, self.ctx
-        rows = jnp.asarray(lg.rows)
-        cols = jnp.asarray(lg.cols)
-        deg_full = jnp.asarray(lg.degrees_full)
-        send_gather = jnp.asarray(lg.send_gather)
         k = lg.k
 
         local = functools.partial(
@@ -85,7 +89,7 @@ class GraphEngine:
         vstep = jax.vmap(local)
 
         @jax.jit
-        def step(state):  # state: [k, v_max]
+        def step(state, rows, cols, deg_full, send_gather):  # state: [k, v_max]
             send = state[jnp.arange(k)[:, None, None], send_gather]  # [k,k,h]
             recv = jnp.transpose(send, (1, 0, 2))  # all-to-all
             return vstep(state, recv, rows, cols, deg_full)
@@ -94,16 +98,18 @@ class GraphEngine:
 
     def run_simulated(self, iters: int) -> np.ndarray:
         state = jnp.asarray(self.program.init_state(self.lg, self.ctx))
+        arrays = tuple(jnp.asarray(a) for a in self.graph_arrays())
         step = self._sim_step
         for _ in range(iters):
-            state = step(state)
-        return self._gather_global(np.asarray(state))
+            state = step(state, *arrays)
+        return self.gather_global(np.asarray(state))
 
     # ------------------------------------------------------------ shard_map
     def build_sharded(self, mesh: Mesh, axis: str = "w", iters: int = 1):
-        """Returns (fn, sharded_inputs). ``fn(state)`` runs ``iters``
-        iterations under ``shard_map`` on ``mesh`` (one device per
-        partition along ``axis``)."""
+        """Returns ``(fn, sharding)``. ``fn(state, *graph_arrays)`` runs
+        ``iters`` iterations under ``shard_map`` on ``mesh`` (one device per
+        partition along ``axis``); every argument is placed with
+        ``sharding``."""
         lg, program, ctx = self.lg, self.program, self.ctx
         if mesh.shape[axis] != lg.k:
             raise ValueError(
@@ -129,50 +135,38 @@ class GraphEngine:
             return out[None]
 
         spec = P(axis)
-        shard = shard_map(
-            device_fn,
-            mesh=mesh,
-            in_specs=(spec, spec, spec, spec, spec),
-            out_specs=spec,
-        )
-        sharding = NamedSharding(mesh, spec)
-        inputs = dict(
-            rows=jax.device_put(self.lg.rows, sharding),
-            cols=jax.device_put(self.lg.cols, sharding),
-            deg_full=jax.device_put(self.lg.degrees_full, sharding),
-            send_gather=jax.device_put(self.lg.send_gather, sharding),
-        )
-
-        @jax.jit
-        def fn(state):
-            return shard(
-                state,
-                inputs["rows"],
-                inputs["cols"],
-                inputs["deg_full"],
-                inputs["send_gather"],
+        fn = jax.jit(
+            shard_map(
+                device_fn,
+                mesh=mesh,
+                in_specs=(spec, spec, spec, spec, spec),
+                out_specs=spec,
             )
-
-        return fn, sharding
+        )
+        return fn, NamedSharding(mesh, spec)
 
     def run_sharded(self, mesh: Mesh, iters: int, axis: str = "w") -> np.ndarray:
         fn, sharding = self.build_sharded(mesh, axis=axis, iters=iters)
-        state = jax.device_put(
-            jnp.asarray(self.program.init_state(self.lg, self.ctx)), sharding
-        )
-        out = fn(state)
-        return self._gather_global(np.asarray(out))
+        state = jax.device_put(self.program.init_state(self.lg, self.ctx), sharding)
+        arrays = [jax.device_put(a, sharding) for a in self.graph_arrays()]
+        out = fn(state, *arrays)
+        return self.gather_global(np.asarray(out))
 
     def lower_sharded(self, mesh: Mesh, iters: int, axis: str = "w"):
-        """Lower (no execution) for dry-run/roofline inspection."""
+        """Lower (no execution) for dry-run/roofline inspection; needs only
+        the mesh's device descriptions, not the devices."""
         fn, sharding = self.build_sharded(mesh, axis=axis, iters=iters)
         state_spec = jax.ShapeDtypeStruct(
             (self.lg.k, self.lg.v_max), jnp.float32, sharding=sharding
         )
-        return jax.jit(fn).lower(state_spec)
+        specs = [
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+            for a in self.graph_arrays()
+        ]
+        return fn.lower(state_spec, *specs)
 
     # -------------------------------------------------------------- helpers
-    def _gather_global(self, state_kv: np.ndarray) -> np.ndarray:
+    def gather_global(self, state_kv: np.ndarray) -> np.ndarray:
         out = np.zeros(self.lg.num_vertices, dtype=state_kv.dtype)
         for p in range(self.lg.k):
             c = int(self.lg.local_count[p])

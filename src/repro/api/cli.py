@@ -331,6 +331,9 @@ def _cmd_serve_bench(args) -> int:
 
 def main(argv=None) -> int:
     args = _build_parser().parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.cmd == "list":
         return _cmd_list()
     if args.cmd == "serve-bench":

@@ -1,19 +1,10 @@
-"""JAX version-compatibility shims: the ONE place API drift gets absorbed.
+"""The jax surfaces the repo pins, in one place.
 
-The model/train substrate targets the jax 0.4.3x line but newer jax renamed
-or moved two load-bearing surfaces:
-
-* the ambient-mesh context: ``jax.set_mesh`` (newest) was previously
-  ``jax.sharding.use_mesh``, and before that the ``Mesh`` object itself was
-  the context manager;
-* ``shard_map``: promoted from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, with ``check_rep`` renamed to ``check_vma``.
-
-Every call site in ``repro`` imports :func:`use_mesh` / :func:`shard_map`
-from here instead of touching ``jax`` directly, so the next rename lands in
-this file and nowhere else. ``JAX_VERSION`` / ``MIN_JAX_VERSION`` make the
-supported range introspectable (and testable) at runtime; the declared pip
-range lives in ``pyproject.toml``.
+The repo is written for the jax 0.9 line (see ``pyproject.toml``). Call sites
+import :func:`use_mesh` / :func:`shard_map` from here rather than from
+``jax`` directly, so a future rename lands in this file and nowhere else.
+``JAX_VERSION`` / ``MIN_JAX_VERSION`` make the supported floor
+introspectable (and testable) at runtime.
 """
 from __future__ import annotations
 
@@ -43,47 +34,23 @@ def _parse_version(v: str) -> tuple[int, int, int]:
 #: the running jax version as an (major, minor, patch) int tuple
 JAX_VERSION: tuple[int, int, int] = _parse_version(jax.__version__)
 
-#: oldest jax this substrate is tested against (see pyproject.toml)
-MIN_JAX_VERSION: tuple[int, int, int] = (0, 4, 30)
+#: oldest jax this repo supports (see pyproject.toml)
+MIN_JAX_VERSION: tuple[int, int, int] = (0, 9, 0)
 
 
 def jax_at_least(*version: int) -> bool:
-    """True when the running jax is >= ``version`` (e.g. ``(0, 5)``)."""
+    """True when the running jax is >= ``version`` (e.g. ``(0, 9)``)."""
     return JAX_VERSION >= tuple(version)
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Newest jax spells this ``jax.set_mesh``; the 0.5/0.6 line had
-    ``jax.sharding.use_mesh``; on the 0.4.x line the ``Mesh`` object itself
-    is the context manager (entering it sets the physical resource env that
-    ``with_sharding_constraint`` and bare-``PartitionSpec`` lowering read).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    sharding_use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if sharding_use_mesh is not None:
-        return sharding_use_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Maps ``check_vma`` onto the old ``check_rep`` flag when running on a jax
-    that still hosts shard_map under ``jax.experimental``.
-    """
-    new_shard_map = getattr(jax, "shard_map", None)
-    if new_shard_map is not None:
-        return new_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the repo's keyword spelling."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )

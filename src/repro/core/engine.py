@@ -1440,10 +1440,17 @@ class StreamEngine:
         self.config = config or EngineConfig()
         self.ids = stream_order(graph, order, seed) if ids is None else ids
         self.on_chunk_end = on_chunk_end
+        self._use_kernel = kernel_active(self.config.use_pallas, self.config.interpret)
         # run counters consumed by repro.api's PartitionResult telemetry:
-        # kernel_calls counts fused chunk-histogram calls, single_place_calls
-        # the host-scored placements (buffered policy); policies add their own
-        self.telemetry: dict = {"kernel_calls": 0, "single_place_calls": 0}
+        # kernel_calls counts fused chunk-histogram calls, kernel_active says
+        # whether they ran the Pallas kernel (else the host bincount),
+        # single_place_calls the host-scored placements (buffered policy);
+        # policies add their own
+        self.telemetry: dict = {
+            "kernel_calls": 0,
+            "kernel_active": self._use_kernel,
+            "single_place_calls": 0,
+        }
         self.prefetch_enabled, self.prefetch_ahead = _resolve_prefetch(
             self.config.prefetch, graph
         )
@@ -1451,7 +1458,6 @@ class StreamEngine:
         self._sample_rng = np.random.default_rng(seed)
         self._pos = np.full(graph.num_vertices, -1, dtype=np.int64)
         self._zero_sizes = np.zeros(state.k, dtype=np.float32)
-        self._use_kernel = kernel_active(self.config.use_pallas, self.config.interpret)
 
     def run(self) -> PartitionState:
         self.scorer.begin(self.state)

@@ -49,12 +49,9 @@ def _attn_kernel(
 
     def body(ki, carry):
         m_prev, l_prev, acc = carry
-        # index the leading block axis with a length-1 Slice, not a Python
-        # int: pallas' load discharge requires every non-Slice index to be a
-        # shaped array, so a bare 0 breaks under interpret mode
-        blk_idx = (pl.dslice(0, 1), pl.dslice(ki * block_k, block_k), slice(None))
-        k_blk = pl.load(k_ref, blk_idx)[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, blk_idx)[0].astype(jnp.float32)
+        kv_rows = pl.ds(ki * block_k, block_k)
+        k_blk = k_ref[0, kv_rows, :].astype(jnp.float32)
+        v_blk = v_ref[0, kv_rows, :].astype(jnp.float32)
         s = q @ k_blk.T  # [BQ, BK]
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.partition_score.partition_score import (
-    fennel_scores_pallas,
-    fennel_scores_sharded_pallas,
-)
+from repro.kernels.partition_score.partition_score import fennel_scores_sharded_pallas
 from repro.kernels.partition_score.ref import (
     fennel_scores_ref,
     fennel_scores_sharded_ref,
@@ -60,23 +57,17 @@ def fennel_scores(
 ):
     """scores[B, K] for a batch of vertices (Eq. 7 affinity + penalty).
 
-    ``nbr_parts`` int[B, D] (-1 padding), ``sizes`` float[K].
+    ``nbr_parts`` int[B, D] (-1 padding), ``sizes`` float[K]. The kernel
+    path is the ``S = 1`` launch of :func:`fennel_scores_sharded` and
+    returns a host array.
     """
-    nbr_parts = jnp.asarray(nbr_parts, jnp.int32)
-    sizes = jnp.asarray(sizes, jnp.float32)
     if not kernel_active(use_pallas, interpret):
-        return fennel_scores_ref(nbr_parts, sizes, alpha, gamma)
-    b, d = nbr_parts.shape
-    block_b = 128 if b >= 128 else 8
-    d_chunk = 128 if d >= 128 else max(8, d)
-    bp = int(np.ceil(b / block_b)) * block_b
-    dp = int(np.ceil(d / d_chunk)) * d_chunk
-    padded = jnp.full((bp, dp), -1, jnp.int32).at[:b, :d].set(nbr_parts)
-    out = fennel_scores_pallas(
-        padded, sizes, alpha, gamma,
-        block_b=block_b, d_chunk=d_chunk, interpret=interpret,
-    )
-    return out[:b]
+        return fennel_scores_ref(
+            jnp.asarray(nbr_parts, jnp.int32), jnp.asarray(sizes, jnp.float32),
+            alpha, gamma,
+        )
+    nbr_parts = np.asarray(nbr_parts, np.int32)
+    return _launch(nbr_parts[None], np.asarray(sizes)[None], alpha, gamma, interpret)[0]
 
 
 def fennel_scores_sharded(
@@ -97,18 +88,39 @@ def fennel_scores_sharded(
     sizes - there the leading batch dimension packs all shards' padded
     frontiers into one (shard, block) grid launch per superstep.
     """
-    nbr_parts = jnp.asarray(nbr_parts, jnp.int32)
-    sizes = jnp.asarray(sizes, jnp.float32)
     if not kernel_active(use_pallas, interpret):
-        return fennel_scores_sharded_ref(nbr_parts, sizes, alpha, gamma)
-    s, c, d = nbr_parts.shape
+        return fennel_scores_sharded_ref(
+            jnp.asarray(nbr_parts, jnp.int32), jnp.asarray(sizes, jnp.float32),
+            alpha, gamma,
+        )
+    return _launch(
+        np.asarray(nbr_parts, np.int32), np.asarray(sizes), alpha, gamma, interpret
+    )
+
+
+def kernel_tiling(c: int, d: int) -> tuple[int, int, int, int]:
+    """``(block_b, d_chunk, padded_c, padded_d)`` of the launch for ``c``
+    candidate rows of neighbour width ``d``."""
     block_b = 128 if c >= 128 else 8
     d_chunk = 128 if d >= 128 else max(8, d)
-    cp = int(np.ceil(max(c, 1) / block_b)) * block_b
-    dp = int(np.ceil(max(d, 1) / d_chunk)) * d_chunk
-    padded = jnp.full((s, cp, dp), -1, jnp.int32).at[:, :c, :d].set(nbr_parts)
+    cp = -(-max(c, 1) // block_b) * block_b
+    dp = -(-max(d, 1) // d_chunk) * d_chunk
+    return block_b, d_chunk, cp, dp
+
+
+def _launch(nbr_parts: np.ndarray, sizes: np.ndarray, alpha, gamma, interpret):
+    """Pad ``int32[S, C, D]`` on the host to the kernel's tiling, run one
+    launch, and return the unpadded ``float32[S, C, K]`` on the host.
+
+    Padding and slicing stay in numpy so that each chunk costs one transfer
+    each way and one compiled program per padded shape, not one per
+    ``(C, D)`` pair."""
+    s, c, d = nbr_parts.shape
+    block_b, d_chunk, cp, dp = kernel_tiling(c, d)
+    padded = np.full((s, cp, dp), -1, dtype=np.int32)
+    padded[:, :c, :d] = nbr_parts
     out = fennel_scores_sharded_pallas(
-        padded, sizes, alpha, gamma,
+        padded, np.asarray(sizes, np.float32), alpha, gamma,
         block_b=block_b, d_chunk=d_chunk, interpret=interpret,
     )
-    return out[:, :c]
+    return np.asarray(out)[:, :c]

@@ -8,9 +8,11 @@ VMEM and builds the histogram with VPU compares against the lane-resident
 partition ids - no scatter, MXU-free, fully vectorised.
 
 Tiling:
-  grid over vertex blocks (BB rows); neighbour axis D is looped inside the
-  kernel in chunks of DC columns so the [BB, DC, K] compare cube stays within
-  VMEM; K is padded to the 128-lane register width by the wrapper.
+  grid over (shard, vertex block of BB rows); the neighbour axis D is read
+  from the ref in chunks of DC columns so the [BB, DC, K] compare cube stays
+  within VMEM. Chunks start at static multiples of DC (``pl.multiple_of``):
+  Mosaic cannot slice a loaded value at a dynamic offset, and a block
+  narrower than 128 lanes (D = DC < 128) is read in one static load.
 """
 from __future__ import annotations
 
@@ -22,42 +24,27 @@ from jax.experimental import pallas as pl
 
 
 def _score_kernel(nbr_ref, size_ref, out_ref, *, alpha, gamma, d_chunk):
-    nbr = nbr_ref[...]  # [BB, D] int32
-    sizes = size_ref[...]  # [1, K] float32
-    bb, d = nbr.shape
-    k = sizes.shape[-1]
-    part_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
-
-    def body(c, hist):
-        chunk = jax.lax.dynamic_slice(nbr, (0, c * d_chunk), (bb, d_chunk))
-        eq = (chunk[:, :, None] == part_ids).astype(jnp.float32)
-        return hist + eq.sum(axis=1)
-
-    steps = d // d_chunk
-    hist = jax.lax.fori_loop(
-        0, steps, body, jnp.zeros((bb, k), jnp.float32)
-    )
-    penalty = alpha * gamma * jnp.power(jnp.maximum(sizes, 0.0), gamma - 1.0)
-    out_ref[...] = hist - penalty
-
-
-def _score_kernel_sharded(nbr_ref, size_ref, out_ref, *, alpha, gamma, d_chunk):
-    """One (shard, vertex-block) grid cell: identical math to ``_score_kernel``
-    but the size row is the *shard's* size view, so the fused penalty differs
-    per shard (the parallel engine's bulk-synchronous local state)."""
-    nbr = nbr_ref[0]  # [BB, D] int32 (leading shard dim is blocked to 1)
+    """One (shard, vertex-block) grid cell. The size row is the *shard's*
+    size view, so the fused penalty may differ per shard (the parallel
+    engine's bulk-synchronous local state)."""
+    _, bb, d = nbr_ref.shape  # leading shard dim is blocked to 1
     sizes = size_ref[0]  # [1, K] float32
-    bb, d = nbr.shape
     k = sizes.shape[-1]
     part_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
 
-    def body(c, hist):
-        chunk = jax.lax.dynamic_slice(nbr, (0, c * d_chunk), (bb, d_chunk))
-        eq = (chunk[:, :, None] == part_ids).astype(jnp.float32)
-        return hist + eq.sum(axis=1)
+    def count(tile):  # [BB, DC] int32 -> [BB, K] float32 (exact up to 2^24)
+        return (tile[:, :, None] == part_ids).astype(jnp.float32).sum(axis=1)
 
-    steps = d // d_chunk
-    hist = jax.lax.fori_loop(0, steps, body, jnp.zeros((bb, k), jnp.float32))
+    if d == d_chunk:
+        hist = count(nbr_ref[0])
+    else:
+        def body(c, hist):
+            start = pl.multiple_of(c * d_chunk, d_chunk)
+            return hist + count(nbr_ref[0, :, pl.ds(start, d_chunk)])
+
+        hist = jax.lax.fori_loop(
+            0, d // d_chunk, body, jnp.zeros((bb, k), jnp.float32)
+        )
     penalty = alpha * gamma * jnp.power(jnp.maximum(sizes, 0.0), gamma - 1.0)
     out_ref[0] = hist - penalty
 
@@ -84,7 +71,7 @@ def fennel_scores_sharded_pallas(
     k = sizes.shape[-1]
     assert c % block_b == 0 and d % d_chunk == 0
     kernel = functools.partial(
-        _score_kernel_sharded, alpha=alpha, gamma=gamma, d_chunk=d_chunk
+        _score_kernel, alpha=alpha, gamma=gamma, d_chunk=d_chunk
     )
     return pl.pallas_call(
         kernel,
@@ -98,33 +85,3 @@ def fennel_scores_sharded_pallas(
         interpret=interpret,
     )(nbr_parts, sizes[:, None, :])
 
-
-@functools.partial(
-    jax.jit, static_argnames=("alpha", "gamma", "block_b", "d_chunk", "interpret")
-)
-def fennel_scores_pallas(
-    nbr_parts: jnp.ndarray,  # int32[B, D] (-1 pad; B % block_b == 0, D % d_chunk == 0)
-    sizes: jnp.ndarray,  # float32[K]
-    alpha: float,
-    gamma: float,
-    block_b: int = 128,
-    d_chunk: int = 128,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    b, d = nbr_parts.shape
-    k = sizes.shape[0]
-    assert b % block_b == 0 and d % d_chunk == 0
-    kernel = functools.partial(
-        _score_kernel, alpha=alpha, gamma=gamma, d_chunk=d_chunk
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(b // block_b,),
-        in_specs=[
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
-        interpret=interpret,
-    )(nbr_parts, sizes[None, :])

@@ -25,11 +25,15 @@ def test_running_jax_inside_declared_range():
         f"jax {jax.__version__} is older than the supported minimum "
         f"{'.'.join(map(str, compat.MIN_JAX_VERSION))}"
     )
+    assert compat.JAX_VERSION[:2] == compat.MIN_JAX_VERSION[:2], (
+        f"jax {jax.__version__} is outside the supported 0.9 line"
+    )
 
 
 def test_jax_at_least():
-    assert compat.jax_at_least(0, 4)
+    assert compat.jax_at_least(0, 9)
     assert compat.jax_at_least(*compat.MIN_JAX_VERSION)
+    assert not compat.jax_at_least(0, 10)
     assert not compat.jax_at_least(99, 0)
 
 
@@ -39,7 +43,12 @@ def test_pyproject_declares_the_same_floor():
     m = re.search(r'"jax>=(\d+)\.(\d+)\.(\d+)', text)
     assert m, "pyproject.toml must declare a jax>=X.Y.Z lower bound"
     assert tuple(int(g) for g in m.groups()) == compat.MIN_JAX_VERSION
-    assert re.search(r'"jaxlib>=', text), "jaxlib range must be declared too"
+    major, minor, _ = compat.MIN_JAX_VERSION
+    upper = f"<{major}.{minor + 1}"
+    for pkg in ("jax", "jaxlib"):
+        assert re.search(rf'"{pkg}>=\d+\.\d+\.\d+,{upper}"', text), (
+            f"{pkg} must be pinned to the {major}.{minor} line"
+        )
 
 
 def test_use_mesh_enables_ambient_sharding():
@@ -63,7 +72,42 @@ def test_compat_shard_map_runs():
 
 
 def test_parse_version_handles_dev_suffixes():
-    assert compat._parse_version("0.4.37") == (0, 4, 37)
-    assert compat._parse_version("0.5.0.dev20250101") == (0, 5, 0)
-    assert compat._parse_version("0.6") == (0, 6, 0)
-    assert compat._parse_version("0.4.37rc1") == (0, 4, 37)
+    assert compat._parse_version("0.9.0") == (0, 9, 0)
+    assert compat._parse_version("0.9.1.dev20260101") == (0, 9, 1)
+    assert compat._parse_version("0.9") == (0, 9, 0)
+    assert compat._parse_version("0.9.0rc1") == (0, 9, 0)
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_enable_compile_cache_placement(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where the cache lands;
+    otherwise the fixed .jax_cache/ at the repo root. Run in a child so
+    the global jax config of this process stays untouched."""
+    import os
+    import subprocess
+    import sys
+
+    repo = Path(__file__).resolve().parent.parent
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+        + ("jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n"
+           if env_dir else "")
+    )
+    res = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        env=env, cwd=repo, timeout=120,
+    )
+    assert res.returncode == 0, res.stderr
+    cache_dir, min_secs = res.stdout.split()[:2]
+    assert float(min_secs) == 0.0
+    if env_dir:
+        assert cache_dir == str(tmp_path)
+        assert any(tmp_path.iterdir()), "no cache entry was written"
+    else:
+        assert cache_dir == str(repo / ".jax_cache")

@@ -167,3 +167,46 @@ def test_benchmark_suite_imports_are_lazy():
     )
     assert res.returncode == 0, res.stderr
     assert "lazy-ok" in res.stdout
+
+
+def test_benchmark_run_exits_nonzero_when_a_suite_errors():
+    """A failing suite still prints its ERROR= line and the other suites
+    still run, but the runner's exit code reports the failure."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    script = textwrap.dedent(
+        """
+        import sys
+        import benchmarks.run as r
+        import repro.compile_cache
+
+        def suite(module, kwargs=None):
+            def run_it():
+                if module == "kernels":
+                    raise RuntimeError("boom")
+                return [{"bench": module}]
+            return run_it
+
+        r._suite = suite
+        repro.compile_cache.enable_compile_cache = lambda: None
+        sys.argv = ["run", "--only", "kernels,imbalance"]
+        try:
+            r.main()
+        except SystemExit as e:
+            print("exit", e.code)
+        """
+    )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    res = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        env=env, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        timeout=120,
+    )
+    assert res.returncode == 0, res.stderr
+    assert "kernels,0.0,ERROR=RuntimeError:boom" in res.stdout
+    assert "# === imbalance ===" in res.stdout
+    assert res.stdout.strip().endswith("exit 1")

@@ -543,3 +543,32 @@ class TestTrajectoryGate:
             doctored, baseline, tolerance=0.15, latency_tolerance=0.75
         )
         assert any("stream_seconds" in r for r in regs)
+
+
+def test_nightly_conversion_child_never_initialises_jax(tmp_path):
+    """scripts/quality_nightly.py runs ``fetch_dataset.py --convert`` in a
+    child process after the parent has partitioned, i.e. while the parent
+    holds the accelerator. The child must not initialise a JAX backend: a
+    second process that reaches for the chip fails or hangs. Here the child
+    sees a platform that does not exist, so any backend start fails it."""
+    import gzip
+    import subprocess
+    import sys
+
+    g = rmat_graph(400, avg_degree=6, seed=2)
+    edges_gz = tmp_path / "edges.txt.gz"
+    with gzip.open(edges_gz, "wt") as fh:
+        np.savetxt(fh, g.edges_array(), fmt="%d")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run(
+        [
+            sys.executable, "scripts/fetch_dataset.py",
+            "--url", edges_gz.resolve().as_uri(), "--name", "child",
+            "--cache-dir", str(tmp_path / "cache"),
+            "--convert", str(tmp_path / "g.bin"),
+        ],
+        capture_output=True, text=True, cwd=repo, timeout=120,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "no_such_platform"},
+    )
+    assert res.returncode == 0, res.stderr
+    assert ExternalCSRGraph(tmp_path / "g.bin").num_edges == g.num_edges
