@@ -62,11 +62,12 @@ def _stream_seconds(result) -> float:
 
 
 def _superstep_ms(result) -> float | None:
-    """Mean per-superstep wall from the sharded-engine profile, or None."""
+    """Mean per-superstep placement wall (the shard-task fan-out and join)
+    from the sharded-engine profile, or None."""
     prof = result.telemetry.get("profile")
     if not isinstance(prof, dict) or not prof.get("supersteps"):
         return None
-    return float(prof["parallel_wall_s"]) / int(prof["supersteps"]) * 1e3
+    return float(prof["place_s"]) / int(prof["supersteps"]) * 1e3
 
 
 def run(n: int = 40_000, avg_degree: int = 12, k: int = 8, seed: int = 0):

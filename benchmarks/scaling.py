@@ -177,15 +177,14 @@ def run(
                 stream_seconds=secs,
                 edge_cut=result.quality()["edge_cut"],
                 speedup=base_s / max(secs, 1e-12),
-                parallel_wall_seconds=prof.get("parallel_wall_s", 0.0),
-                queue_wait_seconds=prof.get("queue_wait_s", 0.0),
+                place_seconds=prof.get("place_s", 0.0),
                 spec=spec.to_dict(),
             ))
             emit(
                 f"scaling/rmat{n}/{algo}/s4/w{workers}",
                 secs * 1e6,
                 f"speedup={base_s / max(secs, 1e-12):.2f}x;"
-                f"queue_wait={prof.get('queue_wait_s', 0.0) * 1e6:.0f}us",
+                f"place={prof.get('place_s', 0.0) * 1e6:.0f}us",
             )
     # chunk sweep (fennel-parallel: the pure superstep engine, no phase 2
     # noise) - feeds the auto-tuner's chunk choice

@@ -53,8 +53,8 @@ class PartitionResult:
     @property
     def profile(self) -> dict | None:
         """Per-superstep wall-clock profile from the parallel engine
-        (``None`` for sequential algorithms): worker count, queue wait, and
-        the prep/score/place/exchange/merge phase split, plus up to 64
+        (``None`` for sequential algorithms): worker count and the main
+        thread's prep/score/place/exchange/merge split, plus up to 64
         per-superstep rows. See :mod:`repro.core.profile`."""
         return self.telemetry.get("profile")
 
@@ -104,7 +104,9 @@ class PartitionResult:
         ``mode="model"``: the v5e-pod cost model (works for edge-cut and
         vertex-cut results alike). ``mode="simulated"``: actually run the
         JAX vertex-program engine in simulated-device mode and report
-        measured halo traffic (edge-cut results only).
+        measured halo traffic (edge-cut results only); ``seconds`` is the
+        wall time of the iterations alone, ``compile_s`` the step's compile
+        before them.
         """
         if mode == "model":
             from repro.analytics import workload_cost
@@ -122,8 +124,6 @@ class PartitionResult:
                 "simulated analytics needs a vertex partition; "
                 "vertex-cut results only support mode='model'"
             )
-        import time
-
         from repro.analytics import GraphEngine, PROGRAMS, localize
 
         if program not in PROGRAMS:
@@ -133,15 +133,15 @@ class PartitionResult:
             )
         lg = localize(self.graph, self.assignment, self.k)
         eng = GraphEngine(lg, PROGRAMS[program]())
-        t0 = time.perf_counter()
-        values = eng.run_simulated(iters)
-        seconds = time.perf_counter() - t0
+        timings: dict = {}
+        values = eng.run_simulated(iters, timings)
         st = eng.stats(iters)
         return {
             "mode": "simulated",
             "program": program,
             "iters": iters,
-            "seconds": seconds,
+            "seconds": timings["seconds"],
+            "compile_s": timings["compile_s"],
             "values": values,
             "halo_messages_per_iter": st.true_halo_messages_per_iter,
             "padded_halo_elements_per_iter": st.padded_halo_elements_per_iter,

@@ -21,7 +21,6 @@ final partition.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -33,6 +32,7 @@ from repro.core.engine import (
     ImmediatePolicy,
     StreamEngine,
 )
+from repro.core.profile import SpanRecorder
 from repro.core.refinement import Refiner, build_subpartition_graph
 from repro.core.subpartition import SubPartitioner
 from repro.graph.csr import CSRGraph
@@ -145,37 +145,39 @@ def partition(
         if use_buffer
         else ImmediatePolicy()
     )
-    # t0 before engine construction: StreamEngine computes stream_order there,
-    # which the seed loop counted inside phase 1
-    t0 = time.perf_counter()
-    engine = StreamEngine(
-        graph,
-        state,
-        FennelScorer(graph, k, params, balance_mode),
-        policy,
-        subpartitioner=subp,
-        order=order,
-        seed=seed,
-        config=EngineConfig(
-            chunk=chunk, use_pallas=use_pallas, interpret=interpret,
-            prefetch=prefetch,
-        ),
-    )
-    engine.run()
-    phase1_s = time.perf_counter() - t0
+    # phase 1 includes engine construction: StreamEngine computes
+    # stream_order there, which the seed loop counted inside phase 1
+    spans = SpanRecorder()
+    with spans.span("partition.phase1"):
+        engine = StreamEngine(
+            graph,
+            state,
+            FennelScorer(graph, k, params, balance_mode),
+            policy,
+            subpartitioner=subp,
+            order=order,
+            seed=seed,
+            config=EngineConfig(
+                chunk=chunk, use_pallas=use_pallas, interpret=interpret,
+                prefetch=prefetch,
+            ),
+            spans=spans,
+        )
+        engine.run()
 
     part = finalize(state)
     sub_of = subp.sub_of.copy()
     kp = subp.kp
     sub_part = np.repeat(np.arange(k, dtype=np.int64), subp.s)
 
-    t1 = time.perf_counter()
     moves, improvement = 0, 0.0
-    if use_refinement and k > 1:
-        part, sub_part, moves, improvement = _phase2_refine(
-            graph, subp, k, epsilon, balance_mode, thresh, max_moves
-        )
-    phase2_s = time.perf_counter() - t1
+    with spans.span("partition.phase2"):
+        if use_refinement and k > 1:
+            part, sub_part, moves, improvement = _phase2_refine(
+                graph, subp, k, epsilon, balance_mode, thresh, max_moves
+            )
+    phase1_s = spans.seconds["partition.phase1"]
+    phase2_s = spans.seconds["partition.phase2"]
 
     if telemetry is not None:
         telemetry.update(engine.telemetry)
@@ -185,6 +187,7 @@ def partition(
             refine_moves=moves,
             refine_improvement=improvement,
             subpartitions=int(kp),
+            spans=spans.to_dict(),
         )
     if return_detail:
         return CuttanaResult(

@@ -63,7 +63,7 @@ from repro.core.base import FennelParams, PartitionState
 from repro.core.buffer import PriorityBuffer
 from repro.core.executor import ShardPool
 from repro.core.priority import BufferStats, make_priority
-from repro.core.profile import SuperstepProfiler
+from repro.core.profile import SpanRecorder
 from repro.core.subpartition import SubPartitioner
 from repro.graph.csr import CSRGraph
 from repro.graph.prefetch import BatchPrefetcher, PrefetchStats
@@ -72,6 +72,7 @@ from repro.kernels.partition_score.ops import (
     fennel_scores,
     fennel_scores_sharded,
     kernel_active,
+    kernel_tiling,
     neighbor_histograms_host,
 )
 
@@ -345,39 +346,40 @@ class ImmediatePolicy:
         for batch, degs, expanded in _iter_chunk_expansions(eng):
             nbr_views = _chunk_views(expanded[2], degs)
             hist, corr = eng.chunk_histograms(batch, degs, nbr_views, expanded)
-            bl = batch.tolist()
-            dl = degs.tolist()
-            for i in range(len(bl)):
-                v, deg = bl[i], dl[i]
-                if reassign:
-                    cur = int(part_of[v])
-                    v_counts[cur] -= 1
-                    e_counts[cur] -= deg
-                    scorer.on_unassign(state, cur, deg)
-                s = scorer.scores(state, hist[i])
-                allowed = ~state.would_overflow(deg)
-                if reassign:
-                    allowed[cur] = True
-                p = state.argmax_tiebreak(s, allowed)
-                if reassign:
-                    part_of[v] = p
-                    v_counts[p] += 1
-                    e_counts[p] += deg
-                    scorer.on_assign(state, p, deg)
-                    if corr is not None and p != cur:
-                        dst, starts = corr
-                        for j in dst[starts[i] : starts[i + 1]]:
-                            hist[j, cur] -= 1.0
-                            hist[j, p] += 1.0
-                else:
-                    state.assign(v, p, deg)
-                    scorer.on_assign(state, p, deg)
-                    if subp is not None:
-                        subp.assign(v, p, nbr_views[i], deg)
-                    if corr is not None:
-                        dst, starts = corr
-                        for j in dst[starts[i] : starts[i + 1]]:
-                            hist[j, p] += 1.0
+            with eng.spans.span("engine.place"):
+                bl = batch.tolist()
+                dl = degs.tolist()
+                for i in range(len(bl)):
+                    v, deg = bl[i], dl[i]
+                    if reassign:
+                        cur = int(part_of[v])
+                        v_counts[cur] -= 1
+                        e_counts[cur] -= deg
+                        scorer.on_unassign(state, cur, deg)
+                    s = scorer.scores(state, hist[i])
+                    allowed = ~state.would_overflow(deg)
+                    if reassign:
+                        allowed[cur] = True
+                    p = state.argmax_tiebreak(s, allowed)
+                    if reassign:
+                        part_of[v] = p
+                        v_counts[p] += 1
+                        e_counts[p] += deg
+                        scorer.on_assign(state, p, deg)
+                        if corr is not None and p != cur:
+                            dst, starts = corr
+                            for j in dst[starts[i] : starts[i + 1]]:
+                                hist[j, cur] -= 1.0
+                                hist[j, p] += 1.0
+                    else:
+                        state.assign(v, p, deg)
+                        scorer.on_assign(state, p, deg)
+                        if subp is not None:
+                            subp.assign(v, p, nbr_views[i], deg)
+                        if corr is not None:
+                            dst, starts = corr
+                            for j in dst[starts[i] : starts[i + 1]]:
+                                hist[j, p] += 1.0
             if eng.on_chunk_end is not None:
                 eng.on_chunk_end(eng, batch, nbr_views)
 
@@ -410,82 +412,83 @@ class ImmediatePolicy:
                 else None
             )
             hist, corr = eng.chunk_histograms(batch, degs, nbr_views, expanded)
-            H = hist.tolist()
-            bl = batch.tolist()
-            dl = degs.tolist()
-            assigned = [0] * len(bl)
-            # python mirrors of the balance state; canonical arrays are
-            # flushed at chunk end (before any on_chunk_end hook), so hooks
-            # may mutate state freely - affine() re-syncs next chunk
-            mul_a, add_a = scorer.affine(state)
-            mul = None if mul_a is None else mul_a.tolist()
-            add = add_a.tolist()
-            v_list = v_counts.tolist()
-            e_list = e_counts.tolist()
-            load = v_list if vertex_mode else e_list
-            for i in range(len(bl)):
-                v, deg = bl[i], dl[i]
-                cur = -1
-                if reassign:
-                    cur = int(part_of[v])  # pre-pass value: writes deferred
-                    v_list[cur] -= 1
-                    e_list[cur] -= deg
-                    u = scorer.affine_update(v_list[cur], e_list[cur])
-                    if mul is not None:
-                        mul[cur] = u[0]
-                    add[cur] = u[1]
-                row = H[i]
-                inc = 1 if vertex_mode else deg
-                best = neg_inf
-                if mul is None:
-                    for p in krange:
-                        if load[p] + inc > cap and p != cur:
-                            sc[p] = neg_inf
-                            continue
-                        s = row[p] + add[p]
-                        sc[p] = s
-                        if s > best:
-                            best = s
-                else:
-                    for p in krange:
-                        if load[p] + inc > cap and p != cur:
-                            sc[p] = neg_inf
-                            continue
-                        s = row[p] * mul[p] + add[p]
-                        sc[p] = s
-                        if s > best:
-                            best = s
-                if best == neg_inf:
-                    # every partition at capacity - least-loaded fallback,
-                    # same rule as PartitionState.argmax_tiebreak
-                    p = load.index(min(load))
-                else:
-                    thr = best - 1e-12
-                    ties = [p for p in krange if sc[p] >= thr]
-                    p = ties[0] if len(ties) == 1 else int(ties[rng.integers(len(ties))])
-                assigned[i] = p
-                v_list[p] += 1
-                e_list[p] += deg
-                u = scorer.affine_update(v_list[p], e_list[p])
-                if mul is not None:
-                    mul[p] = u[0]
-                add[p] = u[1]
-                if subp is not None:
-                    subp.assign(v, p, nbr_views[i], deg)
-                if corr is not None and p != cur:
-                    dst, starts = corr
+            with eng.spans.span("engine.place"):
+                H = hist.tolist()
+                bl = batch.tolist()
+                dl = degs.tolist()
+                assigned = [0] * len(bl)
+                # python mirrors of the balance state; canonical arrays are
+                # flushed at chunk end (before any on_chunk_end hook), so hooks
+                # may mutate state freely - affine() re-syncs next chunk
+                mul_a, add_a = scorer.affine(state)
+                mul = None if mul_a is None else mul_a.tolist()
+                add = add_a.tolist()
+                v_list = v_counts.tolist()
+                e_list = e_counts.tolist()
+                load = v_list if vertex_mode else e_list
+                for i in range(len(bl)):
+                    v, deg = bl[i], dl[i]
+                    cur = -1
                     if reassign:
-                        for j in dst[starts[i] : starts[i + 1]]:
-                            rj = H[j]
-                            rj[cur] -= 1.0
-                            rj[p] += 1.0
+                        cur = int(part_of[v])  # pre-pass value: writes deferred
+                        v_list[cur] -= 1
+                        e_list[cur] -= deg
+                        u = scorer.affine_update(v_list[cur], e_list[cur])
+                        if mul is not None:
+                            mul[cur] = u[0]
+                        add[cur] = u[1]
+                    row = H[i]
+                    inc = 1 if vertex_mode else deg
+                    best = neg_inf
+                    if mul is None:
+                        for p in krange:
+                            if load[p] + inc > cap and p != cur:
+                                sc[p] = neg_inf
+                                continue
+                            s = row[p] + add[p]
+                            sc[p] = s
+                            if s > best:
+                                best = s
                     else:
-                        for j in dst[starts[i] : starts[i + 1]]:
-                            H[j][p] += 1.0
-            # flush deferred writes back into the canonical numpy state
-            part_of[batch] = assigned
-            v_counts[:] = v_list
-            e_counts[:] = e_list
+                        for p in krange:
+                            if load[p] + inc > cap and p != cur:
+                                sc[p] = neg_inf
+                                continue
+                            s = row[p] * mul[p] + add[p]
+                            sc[p] = s
+                            if s > best:
+                                best = s
+                    if best == neg_inf:
+                        # every partition at capacity - least-loaded fallback,
+                        # same rule as PartitionState.argmax_tiebreak
+                        p = load.index(min(load))
+                    else:
+                        thr = best - 1e-12
+                        ties = [p for p in krange if sc[p] >= thr]
+                        p = ties[0] if len(ties) == 1 else int(ties[rng.integers(len(ties))])
+                    assigned[i] = p
+                    v_list[p] += 1
+                    e_list[p] += deg
+                    u = scorer.affine_update(v_list[p], e_list[p])
+                    if mul is not None:
+                        mul[p] = u[0]
+                    add[p] = u[1]
+                    if subp is not None:
+                        subp.assign(v, p, nbr_views[i], deg)
+                    if corr is not None and p != cur:
+                        dst, starts = corr
+                        if reassign:
+                            for j in dst[starts[i] : starts[i + 1]]:
+                                rj = H[j]
+                                rj[cur] -= 1.0
+                                rj[p] += 1.0
+                        else:
+                            for j in dst[starts[i] : starts[i + 1]]:
+                                H[j][p] += 1.0
+                # flush deferred writes back into the canonical numpy state
+                part_of[batch] = assigned
+                v_counts[:] = v_list
+                e_counts[:] = e_list
             if eng.on_chunk_end is not None:
                 eng.on_chunk_end(eng, batch, nbr_views)
 
@@ -539,29 +542,31 @@ class BufferedPolicy:
         # cascade/eviction rows stay data-dependent per-row reads
         for batch, degs, expanded in _iter_chunk_expansions(eng):
             views = _chunk_views(expanded[2], degs)
-            for i, v in enumerate(batch.tolist()):
-                if part_of[v] != -1:
-                    continue  # already placed via complete-eviction cascade
-                nbrs = views[i]
-                if nbrs.size >= d_max:
-                    stats.bypass += 1
-                    cascade(v, nbrs)
-                    continue
-                nbr_parts = part_of[nbrs]
-                assigned = int((nbr_parts != -1).sum())
-                if assigned == nbrs.size and nbrs.size > 0:
-                    cascade(v, nbrs)  # complete already
-                    continue
-                buf.push(v, nbrs, assigned, nbr_parts if track else None)
-                stats.observe_len(len(buf))
-                if buf.full:
-                    u, un = buf.pop_best()
-                    stats.evictions += 1
-                    cascade(u, un)
-        while len(buf):
-            u, un = buf.pop_best()
-            stats.drained += 1
-            cascade(u, un)
+            with eng.spans.span("engine.place"):
+                for i, v in enumerate(batch.tolist()):
+                    if part_of[v] != -1:
+                        continue  # already placed via complete-eviction cascade
+                    nbrs = views[i]
+                    if nbrs.size >= d_max:
+                        stats.bypass += 1
+                        cascade(v, nbrs)
+                        continue
+                    nbr_parts = part_of[nbrs]
+                    assigned = int((nbr_parts != -1).sum())
+                    if assigned == nbrs.size and nbrs.size > 0:
+                        cascade(v, nbrs)  # complete already
+                        continue
+                    buf.push(v, nbrs, assigned, nbr_parts if track else None)
+                    stats.observe_len(len(buf))
+                    if buf.full:
+                        u, un = buf.pop_best()
+                        stats.evictions += 1
+                        cascade(u, un)
+        with eng.spans.span("engine.place"):
+            while len(buf):
+                u, un = buf.pop_best()
+                stats.drained += 1
+                cascade(u, un)
         eng.telemetry.update(stats.to_telemetry(self.strategy))
 
 
@@ -600,6 +605,7 @@ def _iter_chunk_expansions(eng: "StreamEngine"):
     indptr, indices = eng.graph.indptr, eng.graph.indices
     ids = eng.ids
     chunk = eng.config.chunk
+    span = eng.spans.span
 
     def fetch(start):
         batch = ids[start : start + chunk]
@@ -609,11 +615,18 @@ def _iter_chunk_expansions(eng: "StreamEngine"):
     starts = range(0, ids.shape[0], chunk)
     if not eng.prefetch_enabled:
         for s in starts:
-            yield fetch(s)
+            with span("engine.fetch"):
+                item = fetch(s)
+            yield item
         return
     pf = BatchPrefetcher(fetch, starts, stats=eng.prefetch_stats)
     try:
-        yield from pf
+        while True:
+            with span("engine.fetch"):
+                item = next(pf, None)
+            if item is None:
+                return
+            yield item
     finally:
         pf.close()
 
@@ -728,7 +741,7 @@ class _SuperstepRunner:
         )
         self.wave = max(int(eng.config.wave), 1)
         self.pool = ShardPool(eng.config.max_workers, sharded.num_shards)
-        self.profile = SuperstepProfiler(workers=self.pool.workers)
+        self.spans = eng.spans
         self.prefetch_ahead = eng.prefetch_ahead
         # with an inline (single-worker) pool, prepare_async would run on the
         # calling thread and the ahead-prep overlap would silently vanish; a
@@ -746,9 +759,8 @@ class _SuperstepRunner:
         """Flush the chained sub-partition merges and stop the pool. Must
         run before anything reads ``eng.subp`` state (phase 2)."""
         if self._subp_chain is not None:
-            t0 = time.perf_counter()
-            self._subp_chain.result()
-            self.profile.add("merge", time.perf_counter() - t0)
+            with self.spans.span("engine.merge"):
+                self._subp_chain.result()
             self._subp_chain = None
         if self._prefetch_ex is not None:
             self._prefetch_ex.shutdown(wait=True)
@@ -789,10 +801,8 @@ class _SuperstepRunner:
         hit = all(f is None or f.done() for f in futs)
         t0 = time.perf_counter()
         preps = [f.result() if f is not None else None for f in futs]
-        wait = time.perf_counter() - t0
-        self.profile.add("prep", wait)
         if record and self.eng.prefetch_enabled:
-            self.eng.prefetch_stats.record_wait(wait, hit)
+            self.eng.prefetch_stats.record_wait(time.perf_counter() - t0, hit)
         return preps
 
     # -------------------------------------------------------- histogramming
@@ -805,46 +815,52 @@ class _SuperstepRunner:
         part_of = eng.state.part_of
         indptr, indices = eng.graph.indptr, eng.graph.indices
         num_shards = len(counts)
-        cmax = max(max(counts), 1)
-        max_deg = max(
-            (int(p.degs.max()) for p in preps if p is not None and p.degs.size),
-            default=0,
-        )
-        kw = max(min(max_deg, _EXACT_KERNEL_WIDTH), 1)
-        width = max(8, 1 << (kw - 1).bit_length())
         bounds = np.cumsum(np.asarray(counts, dtype=np.int64))
         starts = bounds - np.asarray(counts, dtype=np.int64)
-        nbr3 = np.full((num_shards, cmax, width), -1, dtype=np.int32)
-        over_rows: list[tuple[int, int]] = []
-        for s, prep in enumerate(preps):
-            if prep is None:
-                continue
-            over = np.flatnonzero(prep.degs > kw)
-            if over.size:
-                fmask = (prep.degs <= kw)[prep.rows]
-                nbr3[s, prep.rows[fmask], prep.idx_in_row[fmask]] = (
-                    part_of[prep.cols[fmask]]
-                )
-                over_rows.extend(
-                    (int(starts[s] + i), int(prep.batch[i])) for i in over
-                )
-            else:
-                nbr3[s, prep.rows, prep.idx_in_row] = part_of[prep.cols]
-        out = np.asarray(
-            fennel_scores_sharded(
-                nbr3, np.zeros((num_shards, k), dtype=np.float32), 0.0, 1.5,
-                use_pallas=eng.config.use_pallas, interpret=eng.config.interpret,
-            ),
-            dtype=np.float64,
-        )
-        hist = np.empty((total, k), dtype=np.float64)
-        for s, c in enumerate(counts):
-            if c:
-                hist[starts[s] : bounds[s]] = out[s, :c]
-        for gi, v in over_rows:
-            # over-width hubs: exact host histogram (Thm. 1 regime)
-            nbp = part_of[indices[indptr[v] : indptr[v + 1]]]
-            hist[gi] = np.bincount(nbp[nbp >= 0], minlength=k)
+        with self.spans.span("score.pack"):
+            cmax = max(max(counts), 1)
+            max_deg = max(
+                (int(p.degs.max()) for p in preps if p is not None and p.degs.size),
+                default=0,
+            )
+            kw = max(min(max_deg, _EXACT_KERNEL_WIDTH), 1)
+            width = max(8, 1 << (kw - 1).bit_length())
+            nbr3 = np.full((num_shards, cmax, width), -1, dtype=np.int32)
+            over_rows: list[tuple[int, int]] = []
+            for s, prep in enumerate(preps):
+                if prep is None:
+                    continue
+                eng.telemetry["score_slots_true"] += prep.cols.shape[0]
+                over = np.flatnonzero(prep.degs > kw)
+                if over.size:
+                    fmask = (prep.degs <= kw)[prep.rows]
+                    nbr3[s, prep.rows[fmask], prep.idx_in_row[fmask]] = (
+                        part_of[prep.cols[fmask]]
+                    )
+                    over_rows.extend(
+                        (int(starts[s] + i), int(prep.batch[i])) for i in over
+                    )
+                else:
+                    nbr3[s, prep.rows, prep.idx_in_row] = part_of[prep.cols]
+        eng.count_padded_slots(num_shards, cmax, width)
+        with self.spans.span("score.launch"):
+            out = np.asarray(
+                fennel_scores_sharded(
+                    nbr3, np.zeros((num_shards, k), dtype=np.float32), 0.0, 1.5,
+                    use_pallas=eng.config.use_pallas, interpret=eng.config.interpret,
+                ),
+                dtype=np.float64,
+            )
+            hist = np.empty((total, k), dtype=np.float64)
+            for s, c in enumerate(counts):
+                if c:
+                    hist[starts[s] : bounds[s]] = out[s, :c]
+        if over_rows:
+            with self.spans.span("score.hubs"):
+                for gi, v in over_rows:
+                    # over-width hubs: exact host histogram (Thm. 1 regime)
+                    nbp = part_of[indices[indptr[v] : indptr[v + 1]]]
+                    hist[gi] = np.bincount(nbp[nbp >= 0], minlength=k)
         return hist
 
     # ------------------------------------------------------- per-shard task
@@ -853,17 +869,14 @@ class _SuperstepRunner:
         vectorised placement. Reads only snapshot arrays and ``prep``;
         writes only this shard's ``hist_rows``/``out`` slices - safe and
         deterministic under any pool scheduling."""
-        t0 = time.perf_counter()
         part_of = self.eng.state.part_of
         if hist_rows is None:
             hist_rows = neighbor_histograms_host(
                 prep.rows, part_of[prep.cols], prep.batch.shape[0], self.k
             )
         old = part_of[prep.batch].astype(np.int64) if self.reassign else None
-        t1 = time.perf_counter()
         self._place_shard(prep, hist_rows, out, room, old)
-        t2 = time.perf_counter()
-        return t1 - t0, t2 - t1, old
+        return old
 
     def _place_shard(self, prep, hist, out, room, old):
         """Wave-vectorised placement of one shard's candidates.
@@ -1008,8 +1021,10 @@ class _SuperstepRunner:
         total = sum(counts)
         if total == 0:
             return None
+        span = self.spans.span
         if preps is None:
-            preps = self.wait_preps(self.prepare_async(batches))
+            with span("engine.prep"):
+                preps = self.wait_preps(self.prepare_async(batches))
         eng.telemetry["kernel_calls"] += 1
         k = self.k
         v_counts, e_counts = state.v_counts, state.e_counts
@@ -1027,105 +1042,87 @@ class _SuperstepRunner:
         starts = bounds - np.asarray(counts, dtype=np.int64)
         assigned_flat = np.empty(total, dtype=np.int64)
         hist_all = None
-        score_s = 0.0
         if eng._use_kernel:
-            t_k = time.perf_counter()
             hist_all = self._histograms_packed(preps, counts, total)
-            score_s += time.perf_counter() - t_k
         # fan out: one task per non-empty shard, each writing its disjoint
         # slice of assigned_flat (and mutating only its own hist rows)
-        t_par = time.perf_counter()
-        futs = []
-        for s, prep in enumerate(preps):
-            if prep is None:
-                continue
-            hist_rows = (
-                hist_all[starts[s] : bounds[s]] if hist_all is not None else None
-            )
-            futs.append(
-                self.pool.submit(
-                    self._shard_task, prep, hist_rows,
-                    assigned_flat[starts[s] : bounds[s]], room,
+        with span("engine.place"):
+            futs = []
+            for s, prep in enumerate(preps):
+                if prep is None:
+                    continue
+                hist_rows = (
+                    hist_all[starts[s] : bounds[s]] if hist_all is not None else None
                 )
-            )
-        place_s = 0.0
-        olds = []
-        for f in futs:
-            h_s, p_s, old = f.result()
-            score_s += h_s
-            place_s += p_s
-            if old is not None:
-                olds.append(old)
-        parallel_wall = time.perf_counter() - t_par
+                futs.append(
+                    self.pool.submit(
+                        self._shard_task, prep, hist_rows,
+                        assigned_flat[starts[s] : bounds[s]], room,
+                    )
+                )
+            olds = [old for old in (f.result() for f in futs) if old is not None]
         # ------------------------------------------------ boundary exchange
-        t_x = time.perf_counter()
-        live = [p for p in preps if p is not None]
-        big = np.concatenate([p.batch for p in live])
-        degf = np.concatenate([p.degs for p in live]).astype(np.float64)
-        if self.reassign:
-            old_flat = np.concatenate(olds)
-            v_counts -= np.bincount(old_flat, minlength=k).astype(np.float64)
-            e_counts -= np.bincount(old_flat, weights=degf, minlength=k)
-        state.part_of[big] = assigned_flat
-        v_counts += np.bincount(assigned_flat, minlength=k).astype(np.float64)
-        e_counts += np.bincount(assigned_flat, weights=degf, minlength=k)
-        self.sync_rounds += 1
-        self.step_mark[big] = self.step
-        conflicts = 0
-        for s, prep in enumerate(preps):
-            if prep is None or prep.cols.size == 0:
-                continue
-            same_step = self.step_mark[prep.cols] == self.step
-            conflicts += int((same_step & (self.shard_of[prep.cols] != s)).sum())
-        # each conflicting edge appears once from either endpoint
-        self.boundary_conflicts += conflicts // 2
-        exchange_s = time.perf_counter() - t_x
+        with span("engine.exchange"):
+            live = [p for p in preps if p is not None]
+            big = np.concatenate([p.batch for p in live])
+            degf = np.concatenate([p.degs for p in live]).astype(np.float64)
+            if self.reassign:
+                old_flat = np.concatenate(olds)
+                v_counts -= np.bincount(old_flat, minlength=k).astype(np.float64)
+                e_counts -= np.bincount(old_flat, weights=degf, minlength=k)
+            state.part_of[big] = assigned_flat
+            v_counts += np.bincount(assigned_flat, minlength=k).astype(np.float64)
+            e_counts += np.bincount(assigned_flat, weights=degf, minlength=k)
+            self.sync_rounds += 1
+            self.step_mark[big] = self.step
+            conflicts = 0
+            for s, prep in enumerate(preps):
+                if prep is None or prep.cols.size == 0:
+                    continue
+                same_step = self.step_mark[prep.cols] == self.step
+                conflicts += int((same_step & (self.shard_of[prep.cols] != s)).sum())
+            # each conflicting edge appears once from either endpoint
+            self.boundary_conflicts += conflicts // 2
+            placed = big
+            if self.need_cols:
+                placed = np.concatenate([p.cols for p in live])
+                if self.need_parts:
+                    # partition of the *placer*, aligned with its neighbour slots
+                    parts_all = np.concatenate(
+                        [
+                            assigned_flat[starts[s] : bounds[s]][p.rows]
+                            for s, p in enumerate(preps)
+                            if p is not None
+                        ]
+                    )
+                    placed = (placed, parts_all)
         # ----------------------------------- overlapped sub-partition merge
-        merge_s = 0.0
         if eng.subp is not None:
-            t_m = time.perf_counter()
-            rows_g = np.concatenate(
-                [p.rows + starts[s] for s, p in enumerate(preps) if p is not None]
-            )
-            cols_g = np.concatenate([p.cols for p in live])
-            degs_g = np.concatenate([p.degs for p in live])
-            # FIFO-chained: superstep t's sub-placement may overlap t+1's
-            # scoring (placement never reads sub-partition state), but
-            # merges apply in superstep order and close() flushes the chain
-            # before phase 2 reads it
-            self._subp_chain = self.pool.submit_after(
-                self._subp_chain, eng.subp.assign_superstep,
-                big, assigned_flat, degs_g, rows_g, cols_g, self.wave,
-            )
-            merge_s = time.perf_counter() - t_m
-        self.profile.record(
-            score=score_s, place=place_s, exchange=exchange_s, merge=merge_s,
-            parallel_wall=parallel_wall,
-        )
-        if self.need_cols:
-            cols_all = np.concatenate([p.cols for p in live])
-            if self.need_parts:
-                # partition of the *placer*, aligned with its neighbour slots
-                parts_all = np.concatenate(
-                    [
-                        assigned_flat[starts[s] : bounds[s]][p.rows]
-                        for s, p in enumerate(preps)
-                        if p is not None
-                    ]
+            with span("engine.merge"):
+                rows_g = np.concatenate(
+                    [p.rows + starts[s] for s, p in enumerate(preps) if p is not None]
                 )
-                return cols_all, parts_all
-            return cols_all
-        return big
+                cols_g = np.concatenate([p.cols for p in live])
+                degs_g = np.concatenate([p.degs for p in live])
+                # FIFO-chained: superstep t's sub-placement may overlap t+1's
+                # scoring (placement never reads sub-partition state), but
+                # merges apply in superstep order and close() flushes the
+                # chain before phase 2 reads it
+                self._subp_chain = self.pool.submit_after(
+                    self._subp_chain, eng.subp.assign_superstep,
+                    big, assigned_flat, degs_g, rows_g, cols_g, self.wave,
+                )
+        self.spans.end_superstep()
+        return placed
 
     def finalize_telemetry(self) -> None:
-        self.profile.add_queue_wait(self.pool.queue_wait_s)
         self.eng.telemetry.update(
             supersteps=self.step,
             sync_rounds=self.sync_rounds,
             boundary_conflicts=self.boundary_conflicts,
             num_shards=self.sharded.num_shards,
             max_workers=self.pool.workers,
-            profile=self.profile.to_dict(),
+            profile=self.spans.profile(self.pool.workers),
         )
 
 
@@ -1166,16 +1163,19 @@ class ShardedImmediatePolicy:
                 for batches in steps:
                     runner.run_superstep(batches)
             else:
-                prefetched = runner.prepare_async(steps[0]) if steps else None
+                with eng.spans.span("engine.prep"):
+                    prefetched = runner.prepare_async(steps[0]) if steps else None
                 for t, batches in enumerate(steps):
-                    preps = runner.wait_preps(prefetched, record=True)
-                    # overlap: expand superstep t+1's frontier while t scores,
-                    # places and merges (expansion reads only the immutable CSR)
-                    prefetched = (
-                        runner.prepare_async(steps[t + 1])
-                        if t + 1 < len(steps)
-                        else None
-                    )
+                    with eng.spans.span("engine.prep"):
+                        preps = runner.wait_preps(prefetched, record=True)
+                        # overlap: expand superstep t+1's frontier while t
+                        # scores, places and merges (expansion reads only the
+                        # immutable CSR; inline when the pool has one worker)
+                        prefetched = (
+                            runner.prepare_async(steps[t + 1])
+                            if t + 1 < len(steps)
+                            else None
+                        )
                     runner.run_superstep(batches, preps)
         finally:
             runner.close()
@@ -1362,14 +1362,13 @@ class ShardedBufferedPolicy:
             if prefetch_on:
                 prefetch_scans()
             while True:
-                t0 = time.perf_counter()
-                results = [
-                    f.result()
-                    for f in [
-                        runner.pool.submit(ingest, s) for s in range(num_shards)
+                with eng.spans.span("engine.ingest"):
+                    results = [
+                        f.result()
+                        for f in [
+                            runner.pool.submit(ingest, s) for s in range(num_shards)
+                        ]
                     ]
-                ]
-                runner.profile.add("prep", time.perf_counter() - t0)
                 if prefetch_on:
                     prefetch_scans()
                 batches = [r[0] for r in results]
@@ -1394,13 +1393,12 @@ class ShardedBufferedPolicy:
                     res if track and res is not None else (res, None)
                 )
                 if cols is not None and cols.size:
-                    t1 = time.perf_counter()
-                    for f in [
-                        runner.pool.submit(notify, s, cols, placed_parts)
-                        for s in range(num_shards)
-                    ]:
-                        f.result()
-                    runner.profile.add("merge", time.perf_counter() - t1)
+                    with eng.spans.span("engine.merge"):
+                        for f in [
+                            runner.pool.submit(notify, s, cols, placed_parts)
+                            for s in range(num_shards)
+                        ]:
+                            f.result()
         finally:
             runner.close()
         eng.telemetry.update(bstats.to_telemetry(self.strategy))
@@ -1416,7 +1414,10 @@ class StreamEngine:
     sub-placement into every commit; ``on_chunk_end(engine, batch,
     nbr_views)`` runs after each chunk in immediate mode (HeiStream's FM
     refinement uses it - mutate state there, then call
-    ``engine.scorer.begin(engine.state)`` to refresh the penalty cache)."""
+    ``engine.scorer.begin(engine.state)`` to refresh the penalty cache).
+    ``spans`` is the job's :class:`~repro.core.profile.SpanRecorder` (a new
+    one when not given: a partitioner passes its own to add its phases);
+    after ``run`` its totals are ``telemetry["spans"]``."""
 
     def __init__(
         self,
@@ -1431,6 +1432,7 @@ class StreamEngine:
         ids: np.ndarray | None = None,
         config: EngineConfig | None = None,
         on_chunk_end: Callable[["StreamEngine", np.ndarray, list], None] | None = None,
+        spans: SpanRecorder | None = None,
     ):
         self.graph = graph
         self.state = state
@@ -1438,18 +1440,25 @@ class StreamEngine:
         self.policy = policy
         self.subp = subpartitioner
         self.config = config or EngineConfig()
-        self.ids = stream_order(graph, order, seed) if ids is None else ids
+        self.spans = SpanRecorder() if spans is None else spans
+        if ids is None:
+            with self.spans.span("engine.order"):
+                ids = stream_order(graph, order, seed)
+        self.ids = ids
         self.on_chunk_end = on_chunk_end
         self._use_kernel = kernel_active(self.config.use_pallas, self.config.interpret)
         # run counters consumed by repro.api's PartitionResult telemetry:
         # kernel_calls counts fused chunk-histogram calls, kernel_active says
         # whether they ran the Pallas kernel (else the host bincount),
-        # single_place_calls the host-scored placements (buffered policy);
-        # policies add their own
+        # single_place_calls the host-scored placements (buffered policy),
+        # score_slots_true / score_slots_padded the neighbour slots the
+        # kernel calls had / sent after padding; policies add their own
         self.telemetry: dict = {
             "kernel_calls": 0,
             "kernel_active": self._use_kernel,
             "single_place_calls": 0,
+            "score_slots_true": 0,
+            "score_slots_padded": 0,
         }
         self.prefetch_enabled, self.prefetch_ahead = _resolve_prefetch(
             self.config.prefetch, graph
@@ -1469,7 +1478,14 @@ class StreamEngine:
         decode_s = getattr(self.graph.indices, "decode_seconds", None)
         if decode_s is not None:
             self.telemetry["decode_wall_s"] = round(float(decode_s), 6)
+        self.telemetry["spans"] = self.spans.to_dict()
         return self.state
+
+    def count_padded_slots(self, shards: int, rows: int, width: int) -> None:
+        """Count the neighbour slots one kernel launch of ``shards`` x
+        ``rows`` x ``width`` sends once the kernel's tiling pads it."""
+        _, _, cp, dp = kernel_tiling(rows, width)
+        self.telemetry["score_slots_padded"] += shards * cp * dp
 
     # ------------------------------------------------- per-vertex placement
     def place(self, v: int, nbrs: np.ndarray) -> int:
@@ -1537,52 +1553,63 @@ class StreamEngine:
                 sel = self._sample_rng.choice(nb.size, size=w, replace=False)
                 sampled.append((int(i), part_of[nb[sel]]))
                 scale[i] = nb.size / w
+        span = self.spans.span
         if self._use_kernel:
-            kw = w
-            over: np.ndarray | None = None
-            if cfg.exact and kw > _EXACT_KERNEL_WIDTH:
-                # bound the dense [C, width] matrix: power-law hubs would
-                # otherwise blow it up (one degree-500k vertex => ~1 GB).
-                # The few over-width rows get exact host histograms below.
-                kw = _EXACT_KERNEL_WIDTH
-                over = np.flatnonzero(degs > kw)
-            # pad the neighbour axis to a power of two >= 8 so kernel shapes
-            # stay stable across chunks (padding is -1 and never counted)
-            width = max(8, 1 << (kw - 1).bit_length())
-            nbr_parts = np.full((c, width), -1, dtype=np.int32)
-            if sampled or over is not None:
-                fmask = (degs <= kw)[rows]
-                nbr_parts[rows[fmask], idx_in_row[fmask]] = part_of[cols[fmask]]
-                for i, nbp in sampled:
-                    nbr_parts[i, :kw] = nbp
-            else:
-                nbr_parts[rows, idx_in_row] = part_of[cols]
-            hist = np.asarray(
-                fennel_scores(
-                    nbr_parts, self._zero_sizes, 0.0, 1.5,
-                    use_pallas=cfg.use_pallas, interpret=cfg.interpret,
-                ),
-                dtype=np.float64,
-            )
+            with span("score.pack"):
+                kw = w
+                over: np.ndarray | None = None
+                if cfg.exact and kw > _EXACT_KERNEL_WIDTH:
+                    # bound the dense [C, width] matrix: power-law hubs would
+                    # otherwise blow it up (one degree-500k vertex => ~1 GB).
+                    # The few over-width rows get exact host histograms below.
+                    kw = _EXACT_KERNEL_WIDTH
+                    over = np.flatnonzero(degs > kw)
+                # pad the neighbour axis to a power of two >= 8 so kernel
+                # shapes stay stable across chunks (padding is -1 and never
+                # counted)
+                width = max(8, 1 << (kw - 1).bit_length())
+                nbr_parts = np.full((c, width), -1, dtype=np.int32)
+                if sampled or over is not None:
+                    fmask = (degs <= kw)[rows]
+                    nbr_parts[rows[fmask], idx_in_row[fmask]] = part_of[cols[fmask]]
+                    for i, nbp in sampled:
+                        nbr_parts[i, :kw] = nbp
+                else:
+                    nbr_parts[rows, idx_in_row] = part_of[cols]
+            self.telemetry["score_slots_true"] += rows.shape[0]
+            self.count_padded_slots(1, c, width)
+            with span("score.launch"):
+                hist = np.asarray(
+                    fennel_scores(
+                        nbr_parts, self._zero_sizes, 0.0, 1.5,
+                        use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+                    ),
+                    dtype=np.float64,
+                )
             if over is not None:
-                for i in over.tolist():
-                    v = batch[i]
-                    nbp = part_of[indices[indptr[v] : indptr[v + 1]]]
-                    hist[i] = np.bincount(nbp[nbp >= 0], minlength=state.k)
+                with span("score.hubs"):
+                    for i in over.tolist():
+                        v = batch[i]
+                        nbp = part_of[indices[indptr[v] : indptr[v + 1]]]
+                        hist[i] = np.bincount(nbp[nbp >= 0], minlength=state.k)
         else:
             # CPU: flat bincount companion of the kernel, identical counts
-            if sampled:
-                fmask = (degs <= w)[rows]
-                hist = neighbor_histograms_host(
-                    rows[fmask], part_of[cols[fmask]], c, state.k
-                )
-                for i, nbp in sampled:
-                    hist[i] = np.bincount(nbp[nbp >= 0], minlength=state.k)
-            else:
-                hist = neighbor_histograms_host(rows, part_of[cols], c, state.k)
+            with span("score.bincount"):
+                if sampled:
+                    fmask = (degs <= w)[rows]
+                    hist = neighbor_histograms_host(
+                        rows[fmask], part_of[cols[fmask]], c, state.k
+                    )
+                    for i, nbp in sampled:
+                        hist[i] = np.bincount(nbp[nbp >= 0], minlength=state.k)
+                else:
+                    hist = neighbor_histograms_host(rows, part_of[cols], c, state.k)
         if scale is not None:
             hist *= scale[:, None]
-        corr = self._inchunk_corr(batch, rows, cols) if cfg.exact else None
+        corr = None
+        if cfg.exact:
+            with span("engine.corr"):
+                corr = self._inchunk_corr(batch, rows, cols)
         return hist, corr
 
     def _inchunk_corr(self, batch: np.ndarray, rows: np.ndarray, cols: np.ndarray):

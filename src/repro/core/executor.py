@@ -8,8 +8,6 @@ order and of the worker count. :class:`ShardPool` wraps a
 
 * deterministic degradation - one worker (or one CPU) executes submissions
   inline on the calling thread, no pool, no queue;
-* queue-wait accounting - time between ``submit`` and task start feeds the
-  profiler's ``queue_wait_s``;
 * ``submit_after`` - FIFO-chained tasks (used for the overlapped
   sub-partition merge: superstep t's merge may run while t+1 scores, but
   merges must apply in superstep order).
@@ -22,7 +20,6 @@ of benign scheduling.
 from __future__ import annotations
 
 import os
-import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 
@@ -71,8 +68,6 @@ class ShardPool:
 
     def __init__(self, requested: int | None, num_shards: int):
         self.workers = resolve_workers(requested, num_shards)
-        self.queue_wait_s = 0.0
-        self._lock = threading.Lock()
         self._ex: ThreadPoolExecutor | None = (
             ThreadPoolExecutor(self.workers, thread_name_prefix="shard")
             if self.workers > 1
@@ -85,12 +80,8 @@ class ShardPool:
                 return _InlineFuture(value=fn(*args))
             except BaseException as exc:  # re-raised at .result()
                 return _InlineFuture(exc=exc)
-        submitted = time.perf_counter()
 
         def task():
-            wait = time.perf_counter() - submitted
-            with self._lock:
-                self.queue_wait_s += wait
             if JITTER is not None:
                 time.sleep(JITTER.random() * 0.003)
             return fn(*args)

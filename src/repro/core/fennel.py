@@ -10,12 +10,11 @@ kernel-backed scoring, bit-identical to the seed per-vertex loop kept in
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from repro.core.base import FennelParams, PartitionState, finalize
 from repro.core.engine import EngineConfig, FennelScorer, ImmediatePolicy, StreamEngine
+from repro.core.profile import SpanRecorder
 from repro.graph.csr import CSRGraph
 
 
@@ -35,21 +34,24 @@ def partition(
 ) -> np.ndarray:
     params = params or FennelParams()
     state = PartitionState.create(graph, k, epsilon, balance_mode, seed)
-    t0 = time.perf_counter()
-    engine = StreamEngine(
-        graph,
-        state,
-        FennelScorer(graph, k, params, balance_mode),
-        ImmediatePolicy(),
-        order=order,
-        seed=seed,
-        config=EngineConfig(
-            chunk=chunk, use_pallas=use_pallas, interpret=interpret,
-            prefetch=prefetch,
-        ),
-    )
-    engine.run()
+    spans = SpanRecorder()
+    with spans.span("partition.phase1"):
+        engine = StreamEngine(
+            graph,
+            state,
+            FennelScorer(graph, k, params, balance_mode),
+            ImmediatePolicy(),
+            order=order,
+            seed=seed,
+            config=EngineConfig(
+                chunk=chunk, use_pallas=use_pallas, interpret=interpret,
+                prefetch=prefetch,
+            ),
+            spans=spans,
+        )
+        engine.run()
     if telemetry is not None:
         telemetry.update(engine.telemetry)
-        telemetry["stream_seconds"] = time.perf_counter() - t0
+        telemetry["stream_seconds"] = spans.seconds["partition.phase1"]
+        telemetry["spans"] = spans.to_dict()
     return finalize(state)

@@ -24,8 +24,6 @@ batched streaming latency - measured by the ``scaling`` benchmark suite.
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from repro.core import autotune
@@ -38,6 +36,7 @@ from repro.core.engine import (
     ShardedImmediatePolicy,
     StreamEngine,
 )
+from repro.core.profile import SpanRecorder
 from repro.core.subpartition import SubPartitioner
 from repro.graph.csr import CSRGraph
 
@@ -120,44 +119,45 @@ def partition_parallel(
         balance_mode=balance_mode,
         seed=seed,
     )
-    t0 = time.perf_counter()
-    engine = StreamEngine(
-        graph,
-        state,
-        FennelScorer(graph, k, params, balance_mode),
-        ShardedBufferedPolicy(num_shards, max_qsize, d_max, theta, strategy=strategy),
-        subpartitioner=subp,
-        order=order,
-        seed=seed,
-        config=EngineConfig(
-            chunk=chunk, use_pallas=use_pallas, interpret=interpret,
-            max_workers=max_workers, prefetch=prefetch,
-        ),
-    )
-    engine.run()
-    phase1_s = time.perf_counter() - t0
+    spans = SpanRecorder()
+    with spans.span("partition.phase1"):
+        engine = StreamEngine(
+            graph,
+            state,
+            FennelScorer(graph, k, params, balance_mode),
+            ShardedBufferedPolicy(num_shards, max_qsize, d_max, theta, strategy=strategy),
+            subpartitioner=subp,
+            order=order,
+            seed=seed,
+            config=EngineConfig(
+                chunk=chunk, use_pallas=use_pallas, interpret=interpret,
+                max_workers=max_workers, prefetch=prefetch,
+            ),
+            spans=spans,
+        )
+        engine.run()
 
     part = finalize(state)
     kp = subp.kp
 
-    t1 = time.perf_counter()
     moves, improvement = 0, 0.0
-    if use_refinement and k > 1:
-        # merge + coarsen + refine: the trade pass that reconciles the
-        # shard-boundary vertices the relaxed supersteps mis-scored
-        part, _, moves, improvement = _phase2_refine(
-            graph, subp, k, epsilon, balance_mode, thresh, max_moves
-        )
-    phase2_s = time.perf_counter() - t1
+    with spans.span("partition.phase2"):
+        if use_refinement and k > 1:
+            # merge + coarsen + refine: the trade pass that reconciles the
+            # shard-boundary vertices the relaxed supersteps mis-scored
+            part, _, moves, improvement = _phase2_refine(
+                graph, subp, k, epsilon, balance_mode, thresh, max_moves
+            )
 
     if telemetry is not None:
         telemetry.update(engine.telemetry)
         telemetry.update(
-            phase1_seconds=phase1_s,
-            phase2_seconds=phase2_s,
+            phase1_seconds=spans.seconds["partition.phase1"],
+            phase2_seconds=spans.seconds["partition.phase2"],
             refine_moves=moves,
             refine_improvement=improvement,
             subpartitions=int(kp),
+            spans=spans.to_dict(),
         )
     return part
 
@@ -191,21 +191,24 @@ def fennel_parallel(
     )
     params = params or FennelParams()
     state = PartitionState.create(graph, k, epsilon, balance_mode, seed)
-    t0 = time.perf_counter()
-    engine = StreamEngine(
-        graph,
-        state,
-        FennelScorer(graph, k, params, balance_mode),
-        ShardedImmediatePolicy(num_shards),
-        order=order,
-        seed=seed,
-        config=EngineConfig(
-            chunk=chunk, use_pallas=use_pallas, interpret=interpret,
-            max_workers=max_workers, prefetch=prefetch,
-        ),
-    )
-    engine.run()
+    spans = SpanRecorder()
+    with spans.span("partition.phase1"):
+        engine = StreamEngine(
+            graph,
+            state,
+            FennelScorer(graph, k, params, balance_mode),
+            ShardedImmediatePolicy(num_shards),
+            order=order,
+            seed=seed,
+            config=EngineConfig(
+                chunk=chunk, use_pallas=use_pallas, interpret=interpret,
+                max_workers=max_workers, prefetch=prefetch,
+            ),
+            spans=spans,
+        )
+        engine.run()
     if telemetry is not None:
         telemetry.update(engine.telemetry)
-        telemetry["stream_seconds"] = time.perf_counter() - t0
+        telemetry["stream_seconds"] = spans.seconds["partition.phase1"]
+        telemetry["spans"] = spans.to_dict()
     return finalize(state)

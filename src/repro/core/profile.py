@@ -1,73 +1,145 @@
-"""Lightweight per-superstep profiling for the sharded engine.
+"""The program's one span surface: named main-thread wall-time spans.
 
-The superstep core splits each round into phases and feeds their durations
-here; the aggregate lands in ``telemetry["profile"]`` (and from there in
-:class:`repro.api.result.PartitionResult`). Phases:
+``with spans.span(name):`` adds the main thread's ``perf_counter`` wall
+time and a count to ``name``'s total, and opens a
+``jax.profiler.TraceAnnotation`` of the same name, so under the JAX
+profiler the span sits on the host plane on the same clock as the device
+ops (a benchmark can name an idle gap of the device by the span around
+it). Names come from the fixed tuple :data:`SPANS`; an unknown name is an
+error.
 
-* ``prep``    - frontier expansion (CSR slicing + in-shard correction
-  pairs). Prefetched one superstep ahead, so with >= 2 workers this mostly
-  measures *wait* on an already-running task - small prep_s is the overlap
-  working, not the expansion being free.
-* ``score``   - assigned-neighbour histogramming (host bincount inside the
-  shard tasks, or the packed Pallas call on the main thread).
-* ``place``   - wave-vectorised placement inside the shard tasks.
-* ``exchange`` - the boundary exchange: committing assignments/loads to the
-  shared state and counting cross-shard conflicts.
-* ``merge``   - post-boundary merges: the chained sub-partition pass and the
-  buffered policy's buffer notifications.
+Spans:
 
-``score_s``/``place_s`` are summed across shard tasks, so with W workers
-they may exceed wall time; ``parallel_wall_s`` is the actual start-to-join
-wall of the concurrent section, and ``queue_wait_s`` the summed lag between
-task submission and task start (pool saturation indicator).
+* ``partition.phase1`` / ``partition.phase2`` - a partitioner's streaming
+  pass (engine construction and run) and its refinement; ``result.timings``
+  takes ``phase1_seconds``/``phase2_seconds`` (``stream_seconds`` for the
+  one-phase partitioners) from these totals.
+* ``engine.order`` - the stream order, drawn as the engine is built.
+* ``engine.fetch`` - the next chunk's neighbour expansion, or the wait on the
+  prefetcher that expands it (chunked policies).
+* ``engine.ingest`` - the shard buffers' ingest fan-out and join (parallel
+  CUTTANA).
+* ``engine.prep`` - superstep frontier expansion on the main thread: inline
+  with one worker, else the wait on the expansion tasks.
+* ``score.pack`` - host build of the padded neighbour-partition matrix.
+* ``score.launch`` - the score-kernel call: host pad, dispatch, kernel and
+  copy back.
+* ``score.hubs`` - exact host histograms of rows wider than the kernel.
+* ``score.bincount`` - the host histogram where no kernel runs (CPU).
+* ``engine.corr`` - the exact mode's in-chunk correction lists.
+* ``engine.place`` - placement: a chunk's placement loop and flush, or a
+  superstep's shard-task fan-out and join.
+* ``engine.exchange`` - the superstep boundary commit of assignments and
+  loads, conflict count, and the placed neighbour slots for the buffers.
+* ``engine.merge`` - the sub-partition merge submit, the buffers'
+  notification fan-out and join, and the final flush of the merge chain.
 
-Cost: a few float adds per superstep - safe to leave on unconditionally.
+Spans go on the thread that drives the job only, never in a pool task: a
+span there would hide what the main thread waited on, and would need a
+lock. Spans are per chunk or per superstep, never per vertex or per wave.
+
+The sharded policies' ``telemetry["profile"]`` is a view of the same
+totals (:data:`PHASES`), with the first supersteps' rows.
 """
 from __future__ import annotations
 
-PHASES = ("prep", "score", "place", "exchange", "merge")
+from time import perf_counter
+
+from jax.profiler import TraceAnnotation
+
+SPANS = (
+    "partition.phase1",
+    "partition.phase2",
+    "engine.order",
+    "engine.fetch",
+    "engine.ingest",
+    "engine.prep",
+    "score.pack",
+    "score.launch",
+    "score.hubs",
+    "score.bincount",
+    "engine.corr",
+    "engine.place",
+    "engine.exchange",
+    "engine.merge",
+)
+
+# superstep profile phase -> the spans whose main-thread wall it sums
+PHASES = {
+    "prep": ("engine.prep",),
+    "score": ("score.pack", "score.launch", "score.hubs", "score.bincount"),
+    "place": ("engine.place",),
+    "exchange": ("engine.exchange",),
+    "merge": ("engine.merge",),
+}
 
 
-class SuperstepProfiler:
-    def __init__(self, workers: int, keep: int = 64):
-        self.workers = int(workers)
-        self.totals = {p: 0.0 for p in PHASES}
-        self.parallel_wall_s = 0.0
-        self.queue_wait_s = 0.0
+class _Span:
+    __slots__ = ("_rec", "_name", "_note", "_t0")
+
+    def __init__(self, rec: "SpanRecorder", name: str):
+        if name not in rec.seconds:
+            raise ValueError(f"unknown span {name!r}; expected one of {SPANS}")
+        self._rec = rec
+        self._name = name
+
+    def __enter__(self):
+        # the annotation costs about as much as the rest of the span; open
+        # it only while a profiler trace is being recorded
+        self._note = TraceAnnotation(self._name) if TraceAnnotation.is_enabled() else None
+        if self._note is not None:
+            self._note.__enter__()
+        self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        self._rec.seconds[self._name] += dt
+        self._rec.counts[self._name] += 1
+        return False
+
+
+class SpanRecorder:
+    """Totals of one job's spans (seconds and count per name), and the
+    superstep rows of the sharded policies' profile."""
+
+    def __init__(self, keep: int = 64):
+        self.seconds = dict.fromkeys(SPANS, 0.0)
+        self.counts = dict.fromkeys(SPANS, 0)
         self.supersteps = 0
         self._keep = int(keep)
         self._rows: list[dict] = []
+        self._last = dict.fromkeys(PHASES, 0.0)
 
-    def record(self, *, parallel_wall: float = 0.0, **phase_seconds) -> None:
-        """Account one superstep. ``phase_seconds`` keys must be in
-        :data:`PHASES`; omitted phases count as zero."""
+    def span(self, name: str) -> _Span:
+        return _Span(self, name)
+
+    def phase_seconds(self) -> dict[str, float]:
+        return {p: sum(self.seconds[s] for s in names) for p, names in PHASES.items()}
+
+    def end_superstep(self) -> None:
+        """Close one superstep that placed vertices; the first ``keep`` get a
+        row of the phase time spent since the previous row."""
         self.supersteps += 1
-        for phase, dt in phase_seconds.items():
-            self.totals[phase] += dt
-        self.parallel_wall_s += parallel_wall
         if len(self._rows) < self._keep:
-            row = {p: round(phase_seconds.get(p, 0.0), 6) for p in PHASES}
-            row["parallel_wall"] = round(parallel_wall, 6)
-            self._rows.append(row)
-
-    def add(self, phase: str, seconds: float) -> None:
-        """Accumulate time into a phase outside the per-superstep record
-        (prefetch waits, ingest scans, chain flushes)."""
-        self.totals[phase] += seconds
-
-    def add_queue_wait(self, seconds: float) -> None:
-        self.queue_wait_s += seconds
+            now = self.phase_seconds()
+            self._rows.append({p: round(now[p] - self._last[p], 6) for p in PHASES})
+            self._last = now
 
     def to_dict(self) -> dict:
-        out = {
-            "workers": self.workers,
-            "supersteps": self.supersteps,
-            "parallel_wall_s": round(self.parallel_wall_s, 6),
-            "queue_wait_s": round(self.queue_wait_s, 6),
+        """``{name: {"s": seconds, "n": count}}`` of the spans recorded."""
+        return {
+            name: {"s": self.seconds[name], "n": n}
+            for name, n in self.counts.items() if n
         }
-        for p in PHASES:
-            out[f"{p}_s"] = round(self.totals[p], 6)
-        # first _keep supersteps verbatim: enough to see warmup + steady state
-        # without unbounded growth on million-superstep runs
+
+    def profile(self, workers: int) -> dict:
+        """The superstep profile: main-thread wall per phase, summed over the
+        job, and the first supersteps' rows."""
+        out = {"workers": int(workers), "supersteps": self.supersteps}
+        for p, s in self.phase_seconds().items():
+            out[f"{p}_s"] = round(s, 6)
         out["per_superstep"] = list(self._rows)
         return out

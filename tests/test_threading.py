@@ -70,8 +70,9 @@ def test_profile_telemetry(graph, algo):
     assert 1 <= prof["supersteps"] <= tel["supersteps"]
     for phase in ("prep", "score", "place", "exchange", "merge"):
         assert prof[f"{phase}_s"] >= 0.0
-    assert prof["parallel_wall_s"] >= 0.0
-    assert prof["queue_wait_s"] >= 0.0
+    # place_s is the main thread's wall of the shard-task fan-out and join
+    assert prof["place_s"] == pytest.approx(tel["spans"]["engine.place"]["s"], abs=1e-6)
+    assert prof["place_s"] <= tel["spans"]["partition.phase1"]["s"]
     rows = prof["per_superstep"]
     assert 1 <= len(rows) <= 64
     assert all(set(r) >= {"score", "place", "exchange", "merge"} for r in rows)
@@ -198,7 +199,6 @@ def test_shard_pool_chain_is_fifo_under_threads():
             f = pool.submit_after(f, order.append, i)
         f.result()
         assert order == list(range(32))
-        assert pool.queue_wait_s >= 0.0
     finally:
         executor.JITTER = None
         pool.shutdown()

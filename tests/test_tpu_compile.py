@@ -74,3 +74,37 @@ def test_sharded_pagerank_compiles_for_v5e_mesh(topo):
         mesh, iters=3
     ).compile()
     assert "all-to-all" in compiled.as_text()
+
+
+def test_sharded_pagerank_scopes_survive_the_v5e_compile(topo):
+    """The step's named scopes reach the chip's compiled program as
+    ``op_name`` metadata: the gather and the reduce are found by name, and
+    every op that runs as a scatter lies in the reduce scope."""
+    import re
+    import sys
+    from pathlib import Path
+
+    from repro.analytics import GraphEngine, localize, pagerank_program
+    from repro.core import get_partitioner
+    from repro.graph import rmat_graph
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.trace import hlo_ops_from
+
+    k = 4
+    g = rmat_graph(2000, avg_degree=8, seed=5)
+    lg = localize(g, get_partitioner("fennel")(g, k, seed=0), k)
+    mesh = Mesh(np.array(topo.devices[:k]), ("w",))
+    hlo = GraphEngine(lg, pagerank_program()).lower_sharded(
+        mesh, iters=3
+    ).compile().as_text()
+    gather, reduce_ = hlo_ops_from(hlo, "vp.gather"), hlo_ops_from(hlo, "vp.reduce")
+    assert gather and reduce_ and not gather & reduce_
+    scatter = hlo_ops_from(hlo, "scatter")
+    assert scatter & reduce_
+    for name in scatter - reduce_:  # the scatter's reducer parameters
+        assert re.search(rf"%{re.escape(name)} = \S+ parameter\(", hlo), name
+    assert any(" all-to-all(" in line for line in hlo.splitlines()
+               if any(f"%{n} = " in line for n in hlo_ops_from(hlo, "vp.exchange")))
