@@ -16,9 +16,9 @@ into collective bytes.
 Both modes name the step's phases with ``jax.named_scope``, which reaches
 the compiled program only as ``op_name`` metadata, so a profiler trace finds
 each phase's ops by name: ``vp.send`` (the ghost-table gather of the states
-to send), ``vp.exchange`` (the halo all-to-all), ``vp.gather`` (source states
-and degrees per edge, and the message), ``vp.reduce`` (the segment reduce,
-the only scatter) and ``vp.apply``.
+to send), ``vp.exchange`` (the halo all-to-all), ``vp.gather`` (the message
+of every state slot, then one gather of it per edge), ``vp.reduce`` (the
+segment reduce, the only scatter) and ``vp.apply``.
 """
 from __future__ import annotations
 
@@ -66,7 +66,9 @@ def _local_step(
     with jax.named_scope("vp.gather"):
         identity = jnp.asarray(program.identity, local_state.dtype)
         full = jnp.concatenate([local_state, recv.reshape(-1), identity[None]])
-        msgs = program.message(full[cols], deg_full[cols])
+        # message depends on the source slot alone (``VertexProgram``), so
+        # it is applied per slot and gathered once per edge
+        msgs = program.message(full, deg_full)[cols]
     with jax.named_scope("vp.reduce"):
         agg = _segment_reduce(msgs, rows, v_max + 1, program.reduce_kind, program.identity)
     with jax.named_scope("vp.apply"):

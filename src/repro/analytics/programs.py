@@ -2,7 +2,7 @@
 
 All three paper workloads share one gather-apply skeleton:
 
-    msgs_e   = message(state[col_e], deg[col_e])
+    msgs_e   = message(state, deg)[col_e]
     agg_v    = combine-reduce over edges with row == v
     state_v' = apply(state_v, agg_v, ctx)
 """
@@ -17,6 +17,11 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class VertexProgram:
+    """``message`` is an elementwise function of a source slot's state and
+    degree: the engine applies it to every state slot, then gathers the
+    result once per edge. A program whose message depends on the edge (edge
+    weights) would need a per-edge message, which this API does not have."""
+
     name: str
     identity: float  # identity of the combine reduction
     reduce_kind: str  # "sum" | "min"

@@ -1,4 +1,8 @@
 """Analytics engine correctness vs dense references + cost-model sanity."""
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -67,6 +71,70 @@ def test_sssp_matches_reference(graph, lg):
     finite = np.isfinite(want)
     np.testing.assert_allclose(got[finite], want[finite])
     assert (got[~finite] > 1e30).all()
+
+
+def gather_sizes(hlo: str) -> list[int]:
+    """Element count of every ``gather`` instruction's output in compiled HLO."""
+    return [
+        int(np.prod([int(d) for d in dims.split(",") if d]))
+        for dims in re.findall(r"= \w+\[([\d,]*)\][^ ]* gather\(", hlo)
+    ]
+
+
+PROGRAM_FACTORIES = {
+    "pagerank": pagerank_program,
+    "cc": cc_program,
+    "sssp": lambda: sssp_program(source=7),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAM_FACTORIES))
+def test_one_edge_gather_per_step(lg, name):
+    """The step gathers one array by edge (the per-slot message), beside
+    the ghost-table gather of the states to send."""
+    eng = GraphEngine(lg, PROGRAM_FACTORIES[name]())
+    state = jnp.asarray(eng.program.init_state(lg, eng.ctx))
+    arrays = tuple(jnp.asarray(a) for a in eng.graph_arrays())
+    sizes = gather_sizes(eng._sim_step.lower(state, *arrays).compile().as_text())
+    edge_len, send_len = lg.k * lg.rows.shape[1], lg.send_gather.size
+    assert edge_len != send_len
+    assert sizes.count(edge_len) == 1, sizes
+    assert sizes.count(send_len) == 1, sizes
+
+
+def per_edge_run(lg, program, ctx, iters):
+    """The step with the message applied per edge, after gathering each
+    edge's source state and degree, in plain jnp."""
+
+    def local(st, recv, rows, cols, deg_full):
+        identity = jnp.asarray(program.identity, st.dtype)
+        full = jnp.concatenate([st, recv.reshape(-1), identity[None]])
+        msgs = program.message(full[cols], deg_full[cols])
+        if program.reduce_kind == "sum":
+            agg = jnp.zeros(lg.v_max + 1, st.dtype).at[rows].add(msgs)
+        else:
+            agg = jnp.full(lg.v_max + 1, identity).at[rows].min(msgs)
+        return program.apply(st, agg[: lg.v_max], ctx)
+
+    @jax.jit
+    def step(state, rows, cols, deg_full, send_gather):
+        send = state[jnp.arange(lg.k)[:, None, None], send_gather]
+        recv = jnp.transpose(send, (1, 0, 2))
+        return jax.vmap(local)(state, recv, rows, cols, deg_full)
+
+    state = jnp.asarray(program.init_state(lg, ctx))
+    arrays = tuple(jnp.asarray(a) for a in (lg.rows, lg.cols, lg.degrees_full, lg.send_gather))
+    for _ in range(iters):
+        state = step(state, *arrays)
+    return np.asarray(state)
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAM_FACTORIES))
+def test_per_slot_message_is_bit_identical_to_per_edge(lg, name):
+    eng = GraphEngine(lg, PROGRAM_FACTORIES[name]())
+    got = eng.run_simulated(iters=5)
+    want = eng.gather_global(per_edge_run(lg, eng.program, eng.ctx, iters=5))
+    assert np.array_equal(got, want)
 
 
 def test_partition_quality_reduces_halo_traffic(graph):

@@ -16,6 +16,7 @@ SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
+    import re
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -48,6 +49,17 @@ SCRIPT = textwrap.dedent(
     # the compiled HLO must contain a real all-to-all collective
     txt = eng.lower_sharded(mesh, iters=3).compile().as_text()
     assert "all-to-all" in txt, "halo exchange did not lower to all-to-all"
+
+    # one gather by edge a device (the per-slot message), beside the
+    # ghost-table gather of the states to send
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",") if d]))
+        for dims in re.findall(r"= \\w+\\[([\\d,]*)\\][^ ]* gather\\(", txt)
+    ]
+    edge_len, send_len = lg.rows.shape[1], lg.send_gather.shape[1] * lg.send_gather.shape[2]
+    assert edge_len != send_len
+    assert sizes.count(edge_len) == 1, sizes
+    assert sizes.count(send_len) == 1, sizes
     print(json.dumps({"ok": True, "devices": len(jax.devices())}))
     """
 )
