@@ -13,6 +13,11 @@ The configuration's ``instance_seed`` fixes the vertex labels and the
 partition's stream order, so every run localizes the same partition and
 runs the same compiled shapes; the run's ``--seed`` draws nothing, since
 PageRank has no input beyond the graph.
+
+The control (``control``, read by ``python -m bench.control``) is the
+reference computed in bfloat16, the precision below the float32 state the
+configuration states, read as the largest relative error against the
+float64 reference.
 """
 from __future__ import annotations
 
@@ -24,6 +29,9 @@ from bench import reference
 from bench.graph500 import graph500_csr
 from bench.jobs.partition import job_seed
 from bench.trace import hlo_ops_from
+
+# the smallest scale at which the control has hubs wide enough to bite
+CONTROL_SCALE = 12
 
 
 class Job:
@@ -122,3 +130,40 @@ class Job:
             "max_local_edges": st.max_local_edges,
             "e_max": int(self.engine.lg.e_max),
         }
+
+
+def pagerank_bf16(indptr, indices, iters: int, damping: float) -> np.ndarray:
+    """The reference's power iteration with every value and sum in bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    n = indptr.shape[0] - 1
+    bf = jnp.bfloat16
+    deg = np.diff(indptr)
+    src = jnp.asarray(np.repeat(np.arange(n, dtype=np.int32), deg))
+    dst = jnp.asarray(indices)
+    inv_deg = jnp.asarray(1.0 / np.maximum(deg, 1), dtype=bf)
+
+    @jax.jit
+    def run(x, src, dst, inv_deg):
+        def step(_, x):
+            agg = jax.ops.segment_sum((x * inv_deg)[dst], src, num_segments=n)
+            return bf((1.0 - damping) / n) + bf(damping) * agg
+        return jax.lax.fori_loop(0, iters, step, x)
+
+    x = run(jnp.full(n, 1.0 / n, dtype=bf), src, dst, inv_deg)
+    return np.asarray(x, dtype=np.float64)
+
+
+def pagerank_reading(config, indptr, indices) -> float:
+    iters, damping = int(config["pagerank_iters"]), float(config["damping"])
+    want = reference.pagerank(indptr, indices, iters, damping)
+    got = pagerank_bf16(indptr, indices, iters, damping)
+    return float(np.max(np.abs(got - want) / want))
+
+
+def control(config, traffic, indptr, indices, seed: int) -> dict:
+    """The control's reading (the seed draws nothing here), beside the limit
+    ``Job.check`` holds that number to."""
+    return {"name": "max_rel_err", "limit": traffic["max_rel_err"],
+            "value": pagerank_reading(config, indptr, indices)}

@@ -8,6 +8,12 @@ launch shape they pad to, besides those the warm-up job meets),
 ``reference`` (``fennel`` or ``cuttana_parallel``: the plain reference in
 ``bench/reference.py`` that every answer must equal) and ``reference_args``
 (its keyword arguments beyond the configuration's).
+
+The control (``control``, read by ``python -m bench.control``) is the
+reference with neighbour histograms that count at most the first 1024
+neighbours of a vertex (the score kernel's widest launch), breaking "Eq. 7
+histograms count every assigned neighbour"; it is read as the vertices whose
+part differs from the reference's.
 """
 from __future__ import annotations
 
@@ -17,6 +23,10 @@ import numpy as np
 
 from bench import reference
 from bench.graph500 import graph500_csr
+
+# the smallest scale at which the control has hubs wide enough to bite
+CONTROL_SCALE = 16
+NBR_CAP = 1024
 
 
 def job_seed(seed: int, index: int) -> int:
@@ -161,3 +171,21 @@ class Job:
             "kernel_active": tel.get("kernel_active"),
             "kernel_calls": sorted({r["telemetry"].get("kernel_calls") for r in records}),
         }
+
+
+def partition_reading(config, traffic, indptr, indices, seed: int) -> int:
+    """Vertices placed differently by the capped control and the reference,
+    for window job 1 of a run with ``seed``."""
+    fn = getattr(reference, traffic["reference"])
+    args = dict(epsilon=config["epsilon"], **traffic.get("reference_args", {}))
+    k, s = int(config["k"]), job_seed(seed, 1)
+    want = fn(indptr, indices, k, s, **args)
+    got = fn(indptr, indices, k, s, nbr_cap=NBR_CAP, **args)
+    return int(np.count_nonzero(got != want))
+
+
+def control(config, traffic, indptr, indices, seed: int) -> dict:
+    """The control's reading for one seed, beside the limit ``Job.check``
+    holds that number to."""
+    return {"name": "mismatched_vertices", "limit": 0,
+            "value": partition_reading(config, traffic, indptr, indices, seed)}

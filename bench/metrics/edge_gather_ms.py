@@ -1,9 +1,10 @@
-"""Device milliseconds per PageRank iteration in the edge gather (each edge's
-source state and degree, and its message), on the device that spent the
-most, from the trace. The program names these ops with the scope
-``vp.gather``; the compiled program's metadata maps the scope to the
-instructions (``bench.trace.hlo_ops_from``). None where the program names no
-such scope or the trace holds none of its ops.
+"""Device milliseconds per PageRank iteration in the edge gather (the message
+of every state slot, from its state and degree, then one gather of it per
+edge), on the device that spent the most, from the trace. The program
+names these ops with the scope ``vp.gather``; the compiled program's
+metadata maps the scope to the instructions (``bench.trace.hlo_ops_from``).
+None where the program names no such scope or the trace holds none of its
+ops.
 
 JAX's persistent compilation cache leaves metadata out of its key, so a
 program served from the cache carries the op names of whichever program

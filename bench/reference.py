@@ -18,7 +18,7 @@ Graphs are plain ``(indptr, indices)`` CSR arrays, symmetric, each
 undirected edge stored in both rows. Scores are float64, as the system
 computes them. ``nbr_cap`` makes the neighbour histograms count at most the
 first ``nbr_cap`` neighbours of a vertex: it only serves the control that
-has to fail (``bench/control.py``).
+has to fail (``control`` in ``bench/jobs/partition.py``).
 """
 from __future__ import annotations
 

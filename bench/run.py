@@ -9,7 +9,8 @@ metrics come from ``BENCHMARK.json``; everything else is found by name:
   deployment: graph, ``k``, balance, guarantees;
 * ``bench/traffic/<traffic>.json`` - the job mix; its ``job`` key names
 * ``bench/jobs/<job>.py`` - a ``Job`` class that sets the cell up, runs one
-  job, reports the end-to-end metrics and checks the answers;
+  job, reports the end-to-end metrics and checks the answers, beside the
+  job's control (``control`` and ``CONTROL_SCALE``, see ``bench.control``);
 * ``bench/metrics/<name>.py`` - a ``read(run)`` function per per-layer
   metric, which returns the metric or None where it finds nothing to read.
 
@@ -76,6 +77,12 @@ def load_module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def job_module(traffic: dict):
+    """The job module a traffic mix names by its ``job``."""
+    job = traffic["job"]
+    return load_module(BENCH / "jobs" / f"{job}.py", f"bench_job_{job}")
 
 
 @dataclasses.dataclass
@@ -195,7 +202,7 @@ def main(argv=None) -> int:
     from bench.trace import load as load_trace
 
     meter = CompileMeter(jax)
-    job_mod = load_module(BENCH / "jobs" / f"{cell.traffic['job']}.py", f"bench_job_{cell.traffic['job']}")
+    job_mod = job_module(cell.traffic)
     config = dict(cell.config)
     if args.rehearse:
         config["scale"] = min(config["scale"], REHEARSE_SCALE)

@@ -6,25 +6,19 @@ import pytest
 from bench_util import bench_json
 from bench import control
 from bench.graph500 import graph500_csr
-from bench.run import Cell
+from bench.run import Cell, job_module
 
 CELLS = [w["name"] for w in bench_json()["workloads"]]
-# the smallest scale at which each control has hubs wide enough to bite
-SCALE = {"partition": 16, "pagerank_mesh": 12}
-
-
-def _limit(cell: Cell) -> float:
-    if cell.traffic["job"] == "partition":
-        return 0  # every vertex must match
-    return cell.traffic["max_rel_err"]
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_control_fails_the_limit(cell_name):
+    """At the scale the cell's job module gives its control, against the
+    limit the module says its check holds the number to."""
     cell = Cell.load(cell_name)
-    config = dict(cell.config, scale=SCALE[cell.traffic["job"]])
-    name, value = control.reading(config, cell.traffic, seed=2**32 + 5)
-    assert value > _limit(cell), (name, value)
+    scale = job_module(cell.traffic).CONTROL_SCALE
+    out = control.reading(dict(cell.config, scale=scale), cell.traffic, seed=2**32 + 5)
+    assert out["value"] > out["limit"], out
 
 
 def test_reference_meets_its_own_limit():

@@ -1,6 +1,7 @@
-"""Set-up compiles the score kernel for every launch shape a partition
-cell's window can meet: the shapes come from the traffic's ranges through
-the stream engine's width rounding and the kernel's own tiling rule."""
+"""Set-up compiles the score kernel for every launch shape a cell's window
+can meet, in every cell whose traffic gives the ranges (``warm_rows``): the
+shapes come from the traffic's ranges through the stream engine's width
+rounding and the kernel's own tiling rule."""
 import pytest
 
 from bench_util import bench_json
@@ -9,7 +10,7 @@ from bench.run import Cell
 from repro.kernels.partition_score.ops import kernel_tiling
 
 CELLS = [w["name"] for w in bench_json()["workloads"]
-         if Cell.load(w["name"]).traffic["job"] == "partition"]
+         if "warm_rows" in Cell.load(w["name"]).traffic]
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
