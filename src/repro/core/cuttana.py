@@ -46,15 +46,19 @@ def _phase2_refine(
     balance_mode: str,
     thresh: float,
     max_moves: int | None = None,
+    spans: SpanRecorder | None = None,
 ):
     """Merge + coarsen + refine (paper §III-B): build the sub-partition
     graph from phase-1's sub-assignments and run greedy trades. Shared by
     ``cuttana``, ``cuttana-batched``, ``cuttana-parallel`` (where it is the
     pass that reconciles shard-boundary vertices), and :func:`refine_any`.
+    Records the spans ``refine.*`` in ``spans``.
 
     Returns ``(part, sub_part, moves, cut_improvement)``.
     """
-    w = build_subpartition_graph(graph, subp.sub_of, subp.kp)
+    spans = spans or SpanRecorder()
+    with spans.span("refine.coarsen"):
+        w = build_subpartition_graph(graph, subp.sub_of, subp.kp)
     sub_part = np.repeat(np.arange(k, dtype=np.int64), subp.s)
     if balance_mode == "edge":
         size = subp.sub_e_counts.copy()
@@ -62,8 +66,9 @@ def _phase2_refine(
     else:
         size = subp.sub_v_counts.copy()
         total = float(graph.num_vertices)
-    refiner = Refiner(w, sub_part, size, k, epsilon, total_mass=total)
-    stats = refiner.refine(thresh=thresh, max_moves=max_moves)
+    with spans.span("refine.init"):
+        refiner = Refiner(w, sub_part, size, k, epsilon, total_mass=total)
+    stats = refiner.refine(thresh=thresh, max_moves=max_moves, spans=spans)
     sub_part = refiner.sub_part.copy()
     part = sub_part[subp.sub_of].astype(np.int32)
     return part, sub_part, stats.moves, stats.cut_improvement
@@ -174,7 +179,7 @@ def partition(
     with spans.span("partition.phase2"):
         if use_refinement and k > 1:
             part, sub_part, moves, improvement = _phase2_refine(
-                graph, subp, k, epsilon, balance_mode, thresh, max_moves
+                graph, subp, k, epsilon, balance_mode, thresh, max_moves, spans
             )
     phase1_s = spans.seconds["partition.phase1"]
     phase2_s = spans.seconds["partition.phase2"]

@@ -146,7 +146,7 @@ def partition_parallel(
             # merge + coarsen + refine: the trade pass that reconciles the
             # shard-boundary vertices the relaxed supersteps mis-scored
             part, _, moves, improvement = _phase2_refine(
-                graph, subp, k, epsilon, balance_mode, thresh, max_moves
+                graph, subp, k, epsilon, balance_mode, thresh, max_moves, spans
             )
 
     if telemetry is not None:

@@ -33,10 +33,15 @@ Spans:
   loads, conflict count, and the placed neighbour slots for the buffers.
 * ``engine.merge`` - the sub-partition merge submit, the buffers'
   notification fan-out and join, and the final flush of the merge chain.
+* ``refine.coarsen`` - phase 2's sub-partition graph ``W`` (Def. 3).
+* ``refine.init`` - the refiner's construction: ``M`` and the segment trees.
+* ``refine.best`` - one pick of the next trade (``Refiner.best_move``).
+* ``refine.apply`` - one trade and its updates (``Refiner.apply_move``).
 
 Spans go on the thread that drives the job only, never in a pool task: a
 span there would hide what the main thread waited on, and would need a
-lock. Spans are per chunk or per superstep, never per vertex or per wave.
+lock. Spans are per chunk, per superstep or per refinement move, never per
+vertex or per wave.
 
 The sharded policies' ``telemetry["profile"]`` is a view of the same
 totals (:data:`PHASES`), with the first supersteps' rows.
@@ -62,6 +67,10 @@ SPANS = (
     "engine.place",
     "engine.exchange",
     "engine.merge",
+    "refine.coarsen",
+    "refine.init",
+    "refine.best",
+    "refine.apply",
 )
 
 # superstep profile phase -> the spans whose main-thread wall it sums

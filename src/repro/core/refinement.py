@@ -16,6 +16,14 @@ theoretical property). We maintain, exactly as the paper:
 After a trade we update exactly the O(K') entries of Theorem 2. Feasibility
 (the balance condition) is enforced at query time with a pruned descent of the
 segment tree, so capacity-blocked trades are skipped without being lost.
+
+Tie order. A trade <S_i, dst> is feasible when dst != P'(i) and
+size[i] <= cap - load[dst] + 1e-9. The next trade is the feasible one of
+largest DEC greater than ``thresh``; equal decreases go to the lowest
+sub-partition id i, then to the lowest dst. That is ``np.argmax``'s order
+over the masked [K', K] DEC matrix read row by row, and it depends on
+(W, P', sizes) alone, never on the moves that led there: sub-partition i
+has the leaf i in every partition's trees.
 """
 from __future__ import annotations
 
@@ -23,6 +31,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.core.profile import SpanRecorder
 from repro.graph.csr import CSRGraph
 
 NEG_INF = -np.inf
@@ -32,15 +41,16 @@ def build_subpartition_graph(
     graph: CSRGraph, sub_of: np.ndarray, kp: int
 ) -> np.ndarray:
     """Dense K'xK' weighted sub-partition adjacency; W[i,j] = #edges between
-    members of S_i and S_j (diagonal zeroed; symmetric counts halved once by
-    construction since CSR stores both directions)."""
+    members of S_i and S_j, an integer count (diagonal zeroed). The CSR
+    stores each undirected edge in both rows, so the count matrix is
+    already symmetric: S_i -> S_j slots number the edges between them."""
     src = np.repeat(np.arange(graph.num_vertices, dtype=np.int64), graph.degrees)
     si = sub_of[src].astype(np.int64)
     sj = sub_of[graph.indices].astype(np.int64)
     key = si * kp + sj
     counts = np.bincount(key, minlength=kp * kp).astype(np.float64)
     w = counts.reshape(kp, kp)
-    w = 0.5 * (w + w.T)  # symmetric storage counted each edge twice -> halve
+    w = 0.5 * (w + w.T)  # symmetrises only: the counts are symmetric already
     np.fill_diagonal(w, 0.0)
     return w
 
@@ -77,44 +87,34 @@ class Refiner:
         onehot[np.arange(self.kp), self.sub_part] = 1.0
         self.m = w @ onehot  # M[i, p]
         # ------------------------------------------------------ segment trees
-        # Balance is by MASS, not count: many near-empty sub-partitions can
-        # legally crowd into one partition, so slot capacity must be the
-        # worst case K' (Lemma 1 bounds the EXPECTED count, not the max).
+        # Sub-partition i is leaf i of every (src, dst) tree; the leaf holds
+        # DEC[i, dst] while P'(i) = src, else -inf. (Balance is by mass, so
+        # any number of sub-partitions may share a partition: a tree needs
+        # room for all K' anyway.)
         self.cap2 = 1 << int(np.ceil(np.log2(max(self.kp, 2))))
+        self._leaf_level = self.cap2.bit_length() - 1
         self.tree = np.full((k, k, 2 * self.cap2), NEG_INF, dtype=np.float64)
-        self.owner = np.full((k, self.cap2), -1, dtype=np.int64)
-        self.slot_of = np.full(self.kp, -1, dtype=np.int64)
-        self._free: list[list[int]] = [list(range(self.cap2 - 1, -1, -1)) for _ in range(k)]
-        for i in range(self.kp):
-            self._alloc_slot(i, int(self.sub_part[i]))
         for q in range(k):
             members = np.flatnonzero(self.sub_part == q)
             if members.size:
                 self._write_entries_group(members, q)
 
-    # ------------------------------------------------------------- slot mgmt
-    def _alloc_slot(self, i: int, p: int) -> None:
-        slot = self._free[p].pop()
-        self.slot_of[i] = slot
-        self.owner[p, slot] = i
-
-    def _release_slot(self, i: int, p: int) -> None:
-        slot = int(self.slot_of[i])
-        self.owner[p, slot] = -1
-        self._free[p].append(slot)
-        # clear this slot's leaf across every (p, dst) tree, one repair pass
-        self.tree[p, :, self.cap2 + slot] = NEG_INF
-        self._repair_levels(p, slice(None), self.slot_of[i : i + 1])
+    # ------------------------------------------------------------- leaf ops
+    def _clear_leaf(self, i: int, p: int) -> None:
+        """Empty sub-partition ``i``'s leaf across every (p, dst) tree (it
+        leaves ``p``), one repair pass."""
+        self.tree[p, :, self.cap2 + i] = NEG_INF
+        self._repair_levels(p, slice(None), np.asarray([i]))
 
     # ------------------------------------------------------------- tree ops
-    def _repair_levels(self, src: int, dst_idx, slots: np.ndarray) -> None:
-        """Recompute the internal max nodes above ``slots`` in the
+    def _repair_levels(self, src: int, dst_idx, leaves: np.ndarray) -> None:
+        """Recompute the internal max nodes above ``leaves`` in the
         ``(src, dst)`` trees selected by ``dst_idx`` (a slice for "all
         destinations" or an index array) - ONE level-by-level pass repairs
         any number of dirty leaves, each level a single K-wide ``maximum``
-        instead of the per-(dst, slot) scalar climbs this replaced."""
+        instead of per-(dst, leaf) scalar climbs."""
         t = self.tree[src]
-        nodes = np.unique((np.asarray(slots, dtype=np.int64) + self.cap2) >> 1)
+        nodes = np.unique((np.asarray(leaves, dtype=np.int64) + self.cap2) >> 1)
         while True:
             if isinstance(dst_idx, slice):
                 t[dst_idx, nodes] = np.maximum(
@@ -131,74 +131,82 @@ class Refiner:
     def _write_entries(self, i: int) -> None:
         """(Re)write DEC entries of sub-partition ``i`` for all destinations."""
         p = int(self.sub_part[i])
-        slot = int(self.slot_of[i])
         col = self.m[i] - self.m[i, p]
         col[p] = NEG_INF  # own partition is never a trade destination
-        self.tree[p, :, self.cap2 + slot] = col
-        self._repair_levels(p, slice(None), self.slot_of[i : i + 1])
+        self.tree[p, :, self.cap2 + i] = col
+        self._repair_levels(p, slice(None), np.asarray([i]))
 
     def _write_entries_group(self, members: np.ndarray, q: int) -> None:
         """Batched :meth:`_write_entries` for sub-partitions all living in
         ``q``: one [K, n] leaf write + one repair pass (the Theorem 2 path
         for neighbours in the move's src/dst partitions, whose DEC base
         changed for every destination)."""
-        slots = self.slot_of[members]
         vals = self.m[members] - self.m[members, q][:, None]  # [n, K]
         vals[:, q] = NEG_INF
-        self.tree[q][:, self.cap2 + slots] = vals.T
-        self._repair_levels(q, slice(None), slots)
+        self.tree[q][:, self.cap2 + members] = vals.T
+        self._repair_levels(q, slice(None), members)
 
     def _write_pair_group(self, members: np.ndarray, q: int, src: int, dst: int) -> None:
         """Batched Theorem 2 update for neighbours whose home partition ``q``
         is uninvolved in the move: only their (q, src) and (q, dst) entries
         changed, so two leaf-row writes + one two-row repair pass."""
-        slots = self.slot_of[members]
         base = self.m[members, q]
         t = self.tree[q]
-        t[src, self.cap2 + slots] = self.m[members, src] - base
-        t[dst, self.cap2 + slots] = self.m[members, dst] - base
-        self._repair_levels(q, np.asarray([src, dst]), slots)
+        t[src, self.cap2 + members] = self.m[members, src] - base
+        t[dst, self.cap2 + members] = self.m[members, dst] - base
+        self._repair_levels(q, np.asarray([src, dst]), members)
 
-    def _best_feasible(self, src: int, dst: int, floor: float) -> tuple[int, float] | None:
-        """Best DEC > floor among feasible moves src->dst (pruned descent)."""
+    def _first_leaf(self, node: int) -> int:
+        """The lowest sub-partition id under tree ``node``."""
+        return (node << (self._leaf_level + 1 - node.bit_length())) - self.cap2
+
+    def _best_feasible(
+        self, src: int, dst: int, best_val: float, best_i: int
+    ) -> tuple[int, float] | None:
+        """The feasible trade src -> dst that comes first in the tie order,
+        if it beats ``(best_val, best_i)``: a larger DEC, or an equal DEC at
+        a lower sub-partition id. Pruned descent; ``(i, dec)`` or None."""
         t = self.tree[src, dst]
-        if t[1] <= floor:
+        if t[1] < best_val:
             return None
         room = self.cap - self.part_load[dst]
-        best_slot, best_val = -1, floor
+        found = None
         stack = [1]
         while stack:
             node = stack.pop()
-            if t[node] <= best_val:
+            val = t[node]
+            if val < best_val or (val == best_val and self._first_leaf(node) >= best_i):
                 continue
             if node >= self.cap2:  # leaf
-                slot = node - self.cap2
-                i = self.owner[src, slot]
-                if i >= 0 and self.size[i] <= room + 1e-9:
-                    best_slot, best_val = slot, t[node]
+                i = node - self.cap2
+                if self.size[i] <= room + 1e-9:
+                    best_val, best_i = val, i
+                    found = (i, float(val))
             else:
-                # visit the larger child first for tighter pruning
+                # the larger child first for tighter pruning, the left one
+                # (lower ids) on a tie
                 l, r = 2 * node, 2 * node + 1
                 if t[l] >= t[r]:
                     stack.extend((r, l))
                 else:
                     stack.extend((l, r))
-        return None if best_slot < 0 else (best_slot, best_val)
+        return found
 
     # ------------------------------------------------------------- main API
     def best_move(self, thresh: float = 0.0) -> tuple[int, int, float] | None:
-        """Globally best feasible trade: (sub_part_id, dst, dec) or None."""
+        """The next trade in the tie order (module docstring):
+        ``(sub_part_id, dst, dec)``, or None when no feasible trade lowers
+        the cut by more than ``thresh``."""
         best: tuple[int, int, float] | None = None
-        floor = thresh
+        best_val, best_i = thresh, -1  # an equal DEC never beats thresh
         for src in range(self.k):
             for dst in range(self.k):
                 if src == dst:
                     continue
-                got = self._best_feasible(src, dst, floor)
+                got = self._best_feasible(src, dst, best_val, best_i)
                 if got is not None:
-                    slot, val = got
-                    best = (int(self.owner[src, slot]), dst, float(val))
-                    floor = val
+                    best_i, best_val = got
+                    best = (best_i, dst, best_val)
         return best
 
     def apply_move(self, i: int, dst: int) -> float:
@@ -212,11 +220,10 @@ class Refiner:
         self.m[nbrs, src] -= wvals
         self.m[nbrs, dst] += wvals
         # --- move i itself
-        self._release_slot(i, src)
+        self._clear_leaf(i, src)
         self.sub_part[i] = dst
         self.part_load[src] -= self.size[i]
         self.part_load[dst] += self.size[i]
-        self._alloc_slot(i, dst)
         self._write_entries(i)
         # --- Theorem 2 updates for neighbours, batched per home partition
         if nbrs.size:
@@ -231,19 +238,28 @@ class Refiner:
         return dec
 
     def refine(
-        self, thresh: float = 0.0, max_moves: int | None = None
+        self,
+        thresh: float = 0.0,
+        max_moves: int | None = None,
+        spans: SpanRecorder | None = None,
     ) -> RefineStats:
+        """Apply trades in the tie order until none lowers the cut by more
+        than ``thresh`` (or ``max_moves``); each pick is the span
+        ``refine.best`` and each trade ``refine.apply`` of ``spans``."""
+        span = (spans or SpanRecorder()).span
         stats = RefineStats()
         while True:
             if max_moves is not None and stats.moves >= max_moves:
                 stats.stopped_reason = "max_moves"
                 return stats
-            mv = self.best_move(thresh)
+            with span("refine.best"):
+                mv = self.best_move(thresh)
             if mv is None:
                 stats.stopped_reason = "maximal" if thresh <= 0 else "thresh"
                 return stats
             i, dst, dec = mv
-            got = self.apply_move(i, dst)
+            with span("refine.apply"):
+                got = self.apply_move(i, dst)
             assert abs(got - dec) < 1e-6
             stats.moves += 1
             stats.cut_improvement += got
@@ -260,21 +276,21 @@ class Refiner:
         np.testing.assert_allclose(self.m, self.w @ onehot, atol=1e-6)
         loads = np.bincount(self.sub_part, weights=self.size, minlength=self.k)
         np.testing.assert_allclose(self.part_load, loads, atol=1e-6)
+        ids = np.arange(self.kp)
         for src in range(self.k):
             for dst in range(self.k):
                 if src == dst:
                     continue
                 t = self.tree[src, dst]
-                for slot in range(self.cap2):
-                    i = self.owner[src, slot]
-                    expect = (
-                        self.m[i, dst] - self.m[i, src] if i >= 0 else NEG_INF
-                    )
-                    got = t[self.cap2 + slot]
-                    if i >= 0:
-                        assert abs(got - expect) < 1e-6, (src, dst, slot, got, expect)
-                    else:
-                        assert got == NEG_INF
+                leaves = t[self.cap2 :]
+                expect = np.full(self.cap2, NEG_INF)
+                own = ids[self.sub_part == src]
+                expect[own] = self.m[own, dst] - self.m[own, src]
+                np.testing.assert_allclose(leaves, expect, atol=1e-6)
+                inner = np.arange(1, self.cap2)
+                np.testing.assert_array_equal(
+                    t[inner], np.maximum(t[2 * inner], t[2 * inner + 1])
+                )
 
 
 # --------------------------------------------------------------------- swaps

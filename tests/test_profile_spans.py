@@ -148,6 +148,25 @@ def test_spans_reach_the_partition_result(graph):
     assert "engine.place" in res.telemetry["spans"]
 
 
+REFINE = ("refine.coarsen", "refine.init", "refine.best", "refine.apply")
+
+
+@pytest.mark.parametrize("algo", ["cuttana", "cuttana-parallel"])
+def test_phase2_names_its_parts(graph, algo):
+    res = partition(graph, PartitionSpec(algo=algo, k=4, seed=1, order="random"))
+    spans, moves = res.telemetry["spans"], res.telemetry["refine_moves"]
+    assert moves > 0
+    assert spans["refine.coarsen"]["n"] == spans["refine.init"]["n"] == 1
+    # one pick per trade, and the last pick that finds none
+    assert spans["refine.best"]["n"] == moves + 1
+    assert spans["refine.apply"]["n"] == moves
+    assert sum(spans[n]["s"] for n in REFINE) <= spans["partition.phase2"]["s"]
+    assert res.timings["phase2_seconds"] == spans["partition.phase2"]["s"]
+    off = partition(graph, PartitionSpec(algo=algo, k=4, seed=1, order="random",
+                                         params={"use_refinement": False}))
+    assert not set(REFINE) & set(off.telemetry["spans"])
+
+
 def test_simulated_analytics_reports_compile_apart(graph):
     res = partition(graph, PartitionSpec(algo="fennel", k=2, seed=1))
     out = res.analytics(program="pagerank", iters=3, mode="simulated")
